@@ -55,7 +55,7 @@ ALLOWED_LINE_RE = re.compile(r"\b(?:VirtualCluster|Communicator)\s*\(")
 RAW_RE = re.compile(r"\blax\.(?:psum|all_gather)\s*\(")
 RAW_PRAGMA = "raw-collective:"
 
-SCAN_ROOTS = ("src/repro", "benchmarks", "examples")
+SCAN_ROOTS = ("src/repro", "examples")
 ALLOWED_PATHS = (
     "src/repro/comm/",               # the API itself
 )

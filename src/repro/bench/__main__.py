@@ -3,9 +3,8 @@
 Runs the matrix-driven collective sweep in-process on forced host CPU
 devices, cross-checks every measured config against the plans.py traffic
 model (any mismatch exits non-zero) and writes the schema-versioned JSON
-artifact.  ``--csv`` additionally prints the legacy
-``name,us_per_call,derived`` rows so ``benchmarks/run.py`` can consume the
-output unchanged.
+artifact.  ``--csv`` additionally prints ``name,us_per_call,derived``
+rows.
 
 ``--emit-tuning-table`` instead FOLDS an existing report (``--bench``,
 default the committed ``BENCH_collectives.json``) into the scheme-selection
@@ -74,7 +73,7 @@ def main(argv=None) -> int:
                     help="JSON artifact path (default %(default)s)")
     ap.add_argument("--csv", action="store_true",
                     help="also print name,us_per_call,derived rows "
-                         "(no header: benchmarks/run.py prints its own)")
+                         "(no header)")
     ap.add_argument("--devices", type=int, default=None,
                     help="force this many host devices (default: respect "
                          "XLA_FLAGS, else 8)")
@@ -125,6 +124,9 @@ def main(argv=None) -> int:
     # jax backends initialize on first device query — after the flag above.
     from repro.bench import report, suites
     from repro.bench.validate import BenchValidationError
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     families = tuple(args.families.split(",")) if args.families \
         else suites.FAMILIES

@@ -81,7 +81,7 @@ def copies_per_node(r: CaseResult) -> int:
 
 
 def csv_rows(suite: SuiteResult) -> list[str]:
-    """``name,us_per_call,derived`` rows (benchmarks/run.py format)."""
+    """``name,us_per_call,derived`` rows (the ``--csv`` output)."""
     rows = []
     for r in suite.cases:
         t = r.case.traffic

@@ -1,6 +1,6 @@
 """Calibrated microbenchmark timer.
 
-Fixes the seed timer's two bugs (``benchmarks/_collective_bench.py:timeit``):
+Fixes two bugs of the timer it replaced:
 
 * the warmup expression called ``fn(*xs)`` up to three times (once for the
   ``isinstance`` probe, once per conditional branch) — here warmup is exactly

@@ -51,6 +51,8 @@ from types import MappingProxyType
 from typing import Optional
 
 import jax
+from jax.extend import core as jex_core
+from jax.interpreters.partial_eval import dce_jaxpr
 
 from repro.bench.suites import ELEM_BYTES, BenchCase, _swept
 from repro.comm import registry
@@ -86,12 +88,11 @@ def _aval_bytes(v) -> int:
 
 
 def _inner_jaxprs(eqn):
-    core = jax.extend.core if hasattr(jax, "extend") else jax.core
-    kinds = (core.ClosedJaxpr, core.Jaxpr)
+    kinds = (jex_core.ClosedJaxpr, jex_core.Jaxpr)
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:
-            if isinstance(v, core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
             elif isinstance(v, kinds):
                 yield v
@@ -125,11 +126,48 @@ class LinkEntry:
     group_size: int             # ranks per replica group
 
 
+def _inner_roots(eqn, inner, root) -> dict:
+    """Map ``inner``'s invars to the values they carry in from ``eqn``, so
+    the same value keeps one identity across nested bodies.
+
+    A call-like body (remat, pjit, custom rules) binds the eqn's operands
+    positionally.  A fully unrolled ``scan`` lowers inline, so its consts
+    keep their identity and each ``xs`` slice is the matching slice of the
+    outer array; its carries change every step and bind nothing.  A loop
+    that stays a loop is its own computation, where XLA's CSE cannot reach
+    the outside, and binds nothing either."""
+    if eqn.primitive.name == "scan":
+        length = eqn.params.get("length", 1) or 1
+        if _scan_copies(eqn) != length:
+            return {}
+        nc, ncar = eqn.params["num_consts"], eqn.params["num_carry"]
+        out = {id(i): root(o) for i, o in zip(inner.invars[:nc],
+                                              eqn.invars[:nc])}
+        out.update({id(i): ("xs", root(o)) for i, o in
+                    zip(inner.invars[nc + ncar:], eqn.invars[nc + ncar:])})
+        return out
+    if len(inner.invars) != len(eqn.invars):
+        return {}
+    return {id(i): root(o) for i, o in zip(inner.invars, eqn.invars)}
+
+
 def _walk(jaxpr, sizes: dict, pod_names: set, entries: list,
-          mult: float = 1.0) -> None:
-    # within one jaxpr, identical collective eqns over the same operands are
-    # one HLO op after CSE — count them once
+          mult: float = 1.0, roots: Optional[dict] = None,
+          fwd_seen: Optional[set] = None, in_remat: bool = False) -> None:
+    # identical collective eqns over the same operand values are one HLO op
+    # after XLA's CSE — count them once per body.  Values keep one identity
+    # across nested bodies (``_inner_roots``), which matters for remat: the
+    # compiled program keeps a backward recompute's collective only when no
+    # identical collective ran outside every remat body (its forward twin,
+    # e.g. the weight gather the recompute repeats), while recomputes in
+    # two different remat bodies stay two ops.
+    roots = {} if roots is None else roots
+    fwd_seen = set() if fwd_seen is None else fwd_seen
     seen = set()
+
+    def root(v):
+        return roots.get(id(v), id(v))
+
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         if prim in _AR_LIKE:
@@ -154,7 +192,9 @@ def _walk(jaxpr, sizes: dict, pod_names: set, entries: list,
             # body is the one exception (``unroll`` static copies)
             inner_mult = mult * _scan_copies(eqn) if prim == "scan" else mult
             for inner in _inner_jaxprs(eqn):
-                _walk(inner, sizes, pod_names, entries, inner_mult)
+                _walk(inner, sizes, pod_names, entries, inner_mult,
+                      _inner_roots(eqn, inner, root), fwd_seen,
+                      in_remat or prim == "remat2")
             continue
         if not names:
             continue            # positional-axes only: no wire traffic
@@ -167,11 +207,13 @@ def _walk(jaxpr, sizes: dict, pod_names: set, entries: list,
                 n *= sizes.get(a, 1)
         if n <= 1:
             continue
-        key = (prim, tuple(map(id, eqn.invars)),
+        key = (prim, tuple(map(root, eqn.invars)),
                tuple(sorted((k, repr(v)) for k, v in eqn.params.items())))
-        if key in seen:
+        if key in seen or key in fwd_seen:
             continue
         seen.add(key)
+        if not in_remat:
+            fwd_seen.add(key)
         out_b = sum(_aval_bytes(v) for v in eqn.outvars)
         if kind == "ag":
             link = out_b * (n - 1) / n
@@ -191,10 +233,6 @@ def _walk(jaxpr, sizes: dict, pod_names: set, entries: list,
 
 def _traced_entries(fn, example_args, vc) -> list[LinkEntry]:
     closed = jax.make_jaxpr(fn)(*example_args)
-    try:
-        from jax.interpreters.partial_eval import dce_jaxpr
-    except ImportError:                       # pragma: no cover
-        from jax._src.interpreters.partial_eval import dce_jaxpr
     jaxpr, _ = dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
     sizes = dict(zip(vc.axis_names, vc.axis_shapes))
     entries: list[LinkEntry] = []

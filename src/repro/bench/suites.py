@@ -112,7 +112,7 @@ assert set(QUICK_ELEMS) <= set(FULL_ELEMS)
 
 
 def slug(s: str) -> str:
-    """CSV-safe case name (``benchmarks/run.py`` matches ``^[a-z0-9_]+,``)."""
+    """CSV-safe case name (matches ``^[a-z0-9_]+``)."""
     return re.sub(r"[^a-z0-9]+", "_", s.lower()).strip("_")
 
 

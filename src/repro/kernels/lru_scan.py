@@ -23,16 +23,16 @@ def _kernel(a_ref, x_ref, o_ref, h_ref, *, block_t: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)          # (block_t, bc)
-    x = x_ref[0].astype(jnp.float32)
-
-    def step(t, carry):
-        h = carry * a[t] + x[t]
-        o_ref[0, t] = h.astype(o_ref.dtype)
+    # one (1, block_c) row per step, read and written through the refs: a
+    # dynamic row of a loaded value has no TPU lowering
+    def step(t, h):
+        row = pl.ds(t, 1)
+        h = (h * a_ref[0, row, :].astype(jnp.float32)
+             + x_ref[0, row, :].astype(jnp.float32))
+        o_ref[0, row, :] = h.astype(o_ref.dtype)
         return h
 
-    h = lax.fori_loop(0, block_t, step, h_ref[...])
-    h_ref[...] = h
+    h_ref[...] = lax.fori_loop(0, block_t, step, h_ref[...])
 
 
 def lru_scan_pallas(a: jax.Array, x: jax.Array, *, block_t: int = 256,
@@ -54,6 +54,6 @@ def lru_scan_pallas(a: jax.Array, x: jax.Array, *, block_t: int = 256,
         out_specs=pl.BlockSpec((1, block_t, block_c),
                                lambda b, c, t: (b, t, c)),
         out_shape=jax.ShapeDtypeStruct((B, T, C), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_c,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
         interpret=interpret,
     )(a, x)

@@ -76,20 +76,26 @@ def q4_matmul(a, packed, scales, *, group: int = 32, block_m: int = 128,
               block_n: int = 128, interpret: Optional[bool] = None):
     """``a (M, K) @ dequantize_q4(packed (K//2, N), scales)`` fused.
 
-    K must already divide by ``group`` (the quantizer enforces it); M and N
-    are padded here.  Zero-padding N is sound because a padded column's
-    scale is zero, so its dequantized weights are exactly zero.
+    K must already divide by ``group`` (the quantizer enforces it); M, N
+    and K are padded here.  Zero-padding N and K is sound because a padded
+    column's or group's scale is zero, so its dequantized weights are
+    exactly zero.
     """
     interpret = _default_interpret() if interpret is None else interpret
     M, K = a.shape
     N = packed.shape[1]
+    half_group = group // 2
     bm = min(block_m, M)
-    ap, _ = _pad_to(a, bm, 0)
     bn = block_n if N >= block_n else N
-    pp, _ = _pad_to(packed, bn, 1)
-    sp, _ = _pad_to(scales, bn, 1)
-    out = q4_matmul_pallas(ap, pp, sp, group=group, block_m=block_m,
-                           block_n=block_n, interpret=interpret)
+    bkh = min(128, K // 2)          # packed rows per k step: one lane tile
+    if bkh % half_group:
+        raise ValueError(f"group={group} must divide 2*block_kh={2 * bkh}")
+    ae, _ = _pad_to(_pad_to(a[:, 0::2], bm, 0)[0], bkh, 1)
+    ao, _ = _pad_to(_pad_to(a[:, 1::2], bm, 0)[0], bkh, 1)
+    pp, _ = _pad_to(_pad_to(packed, bn, 1)[0], bkh, 0)
+    sp, _ = _pad_to(_pad_to(scales, bn, 1)[0], bkh // half_group, 0)
+    out = q4_matmul_pallas(ae, ao, pp, sp, group=group, block_m=bm,
+                           block_n=bn, block_kh=bkh, interpret=interpret)
     return out[:M, :N]
 
 

@@ -12,8 +12,11 @@ decode latencies build a session-local tuning overlay.
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
         --requests 32 --slots 4 --max-new 8 --rate 50
 
-``--rate 0`` (the default) submits everything up front — a closed batch,
-useful for a quick throughput number without wall-clock waiting.
+The model runs at its published widths unless ``--reduced`` cuts it to
+``--n-layers`` x ``--d-model`` (as ``repro.launch.train`` does).  ``--rate
+0`` (the default) submits everything up front — a closed batch, useful for
+a quick throughput number without wall-clock waiting.  ``main`` returns the
+finished requests, ``{request id: GenResult}``.
 """
 
 from __future__ import annotations
@@ -24,6 +27,7 @@ import time
 import numpy as np
 
 from repro.data.synthetic import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_by_name
 from repro.serving.queue import AdmissionError
 from repro.serving.scheduler import ContinuousBatchingScheduler
@@ -40,6 +44,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description="continuous-batching serving driver (synthetic load)")
     ap.add_argument("--arch", default="qwen3-0.6b")
+    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--d-model", type=int, default=64)
+    ap.add_argument("--n-layers", type=int, default=2)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-max", type=int, default=24)
@@ -54,8 +61,10 @@ def main(argv=None):
                     help="feed decode latencies into a session-local "
                          "LiveTuner overlay")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    model = build_by_name(args.arch, reduced=True)
+    model = build_by_name(args.arch, reduced=args.reduced,
+                          n_layers=args.n_layers, d_model=args.d_model)
     params = model.init_params(0)
     s_max = args.s_max or (args.prompt_max + args.max_new)
 
@@ -122,8 +131,8 @@ def main(argv=None):
         print(f"  live tuner: serving/{k['scheme']} EWMA {est:.0f} us "
               f"({len(sched.stats)} observations) — overlay has "
               f"{len(tuner.overlay().entries)} entries")
-    return 0
+    return sched.results
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    main()

@@ -4,8 +4,9 @@
         --reduced --steps 100 --batch 8 --seq 128 --mode hier
 
 On the production fleet the same entry point runs under one process per host
-(jax.distributed.initialize); on this container it runs single-process with
-however many devices the platform exposes.
+(jax.distributed.initialize); on one host it runs single-process with
+however many devices the platform exposes.  ``main`` returns the
+``TrainReport``.
 """
 
 from __future__ import annotations
@@ -18,12 +19,13 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.topology import MeshTopology
 from repro.data.synthetic import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_from_topo
 from repro.runtime.steps import make_train_step
 from repro.runtime.train_loop import train
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -36,7 +38,8 @@ def main():
     ap.add_argument("--mode", default="hier", choices=["hier", "naive"])
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--save-every", type=int, default=50)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -49,8 +52,8 @@ def main():
                              compute_dtype=jnp.float32)
     data_cfg = DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                           global_batch=args.batch)
-    train(bundle, steps=args.steps, data_cfg=data_cfg, ckpt_dir=args.ckpt,
-          save_every=args.save_every)
+    return train(bundle, steps=args.steps, data_cfg=data_cfg,
+                 ckpt_dir=args.ckpt, save_every=args.save_every)
 
 
 if __name__ == "__main__":

@@ -283,8 +283,7 @@ class ElasticRuntime:
         mesh, or init fresh when none exists.  Torn steps the checkpointer
         discarded are surfaced for the recovery record."""
         if self.ckpt.latest_step() is None and max_step is None:
-            state = jax.device_put(self.bundle.init_state(self.seed),
-                                   self.shardings)
+            state = self.bundle.init_state(self.seed)
             return state, 0, ()
         template = jax.eval_shape(lambda: self.bundle.init_state(self.seed))
         zeros = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), template)

@@ -21,7 +21,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.substrate.compat import shard_map
 
@@ -89,12 +89,21 @@ class TrainStepBundle:
     state_specs: Any
     batch_spec: Any
     model: Model
+    mesh: Any
 
     def init_state(self, seed: int = 0):
-        params = self.model.init_params(seed)
-        m, v = adamw_init(params)
-        return {"params": params, "m": m, "v": v,
-                "step": jnp.zeros((), jnp.int32)}
+        """Fresh train state, built in place: each device materializes only
+        its own shard of ``state_specs`` over ``mesh``."""
+        def init():
+            params = self.model.init_params(seed)
+            m, v = adamw_init(params)
+            return {"params": params, "m": m, "v": v,
+                    "step": jnp.zeros((), jnp.int32)}
+
+        shardings = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
+                                 self.state_specs,
+                                 is_leaf=lambda x: isinstance(x, P))
+        return jax.jit(init, out_shardings=shardings)()
 
 
 def make_train_step(cfg: ModelConfig, topo: MeshTopology, mesh, *,
@@ -189,7 +198,7 @@ def make_train_step(cfg: ModelConfig, topo: MeshTopology, mesh, *,
         out_specs=(state_specs, {"loss": P(), "gnorm": P(), "tokens": P()}),
         check_vma=False)
     return TrainStepBundle(fn=smapped, state_specs=state_specs,
-                           batch_spec=bspec, model=model)
+                           batch_spec=bspec, model=model, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +339,7 @@ def make_cluster_train_step(cfg: ModelConfig, vc, *, mode: str = "hier",
                       out_specs=(state_specs,
                                  {"loss": P(), "gnorm": P(), "tokens": P()}))
     return TrainStepBundle(fn=smapped, state_specs=state_specs,
-                           batch_spec=bspec, model=model)
+                           batch_spec=bspec, model=model, mesh=vc.mesh)
 
 
 def make_step_bench(cfg: ModelConfig, vc, *, opts=(), unroll: int = 1,

@@ -1,9 +1,9 @@
-"""Virtual-cluster substrate: jax version portability + in-process
+"""Virtual-cluster substrate: the jax mesh API + in-process
 multi-topology testing.
 
-* ``repro.substrate.compat``  — version-portable ``shard_map`` /
-  ``make_mesh`` / axis-type shims (jax 0.4.x–0.7.x).  Import jax mesh and
-  shard_map APIs from here, never from jax directly.
+* ``repro.substrate.compat``  — ``shard_map`` / ``make_mesh`` /
+  ``axis_size`` / axis types for the installed jax (0.9).  Import jax mesh
+  and shard_map APIs from here, never from jax directly.
 * ``repro.substrate.cluster`` — ``VirtualCluster``: builds the two-tier
   (pods x chips) mesh and wraps collective bodies so one check sweeps a
   whole topology matrix in-process.  Call ``ensure_host_device_count(n)``

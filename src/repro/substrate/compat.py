@@ -1,127 +1,46 @@
-"""Version-portable jax API shims (jax 0.4.x through 0.7.x).
+"""The jax mesh and ``shard_map`` API, spelled once for the installed jax (0.9).
 
-jax moved or renamed every API the collectives stack depends on:
-
-==================  ==============================  =============================
-API                 jax 0.4.x                       jax >= 0.6 / 0.7
-==================  ==============================  =============================
-shard_map           jax.experimental.shard_map      jax.shard_map
-replication check   ``check_rep=`` kwarg            ``check_vma=`` kwarg
-make_mesh           no ``axis_types`` kwarg         ``axis_types`` kwarg
-axis types          absent                          jax.sharding.AxisType
-==================  ==============================  =============================
-
-Everything else in the repo imports these names from here instead of from
-jax directly, so a version bump is a change to this one module.  The shims
-are resolved once at import by *introspection* (signature probing), not by
-version comparison — point releases that backport a kwarg keep working.
+Everything else in the repo imports ``shard_map``, ``axis_size``,
+``make_mesh`` and ``auto_axis_types`` from here instead of from jax
+directly, so a version bump is a change to this one module.
 """
 
 from __future__ import annotations
 
-import inspect
-import math
 from typing import Any, Optional, Sequence
 
 import jax
+from jax import lax
 
-
-# ---------------------------------------------------------------------------
-# shard_map: location + replication-check kwarg name
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_KWARGS = frozenset(inspect.signature(_shard_map_impl).parameters)
+AxisType = jax.sharding.AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None,
-              check_rep: Optional[bool] = None, **kwargs):
-    """``jax.shard_map`` under every supported jax.
-
-    ``check_vma`` (new name) and ``check_rep`` (old name) are aliases; pass
-    either and it is forwarded under whatever kwarg the installed jax accepts.
-    ``None`` leaves the installed default in place.
-    """
-    if check_vma is None:
-        check_vma = check_rep
-    kw = dict(kwargs)
+              **kwargs):
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_KWARGS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_KWARGS:
-            kw["check_rep"] = check_vma
-        # else: the installed jax dropped the knob entirely — nothing to do.
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
-
-# ---------------------------------------------------------------------------
-# axis_size: absent from jax.lax on 0.4.x
-# ---------------------------------------------------------------------------
-
-from jax import lax as _lax
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_size(name):
-    """``lax.axis_size`` under every supported jax.
-
-    On 0.4.x (no ``lax.axis_size``) a psum of the constant 1 over the named
-    axis folds to the static axis size.
-    """
-    if hasattr(_lax, "axis_size"):
-        return _lax.axis_size(name)
-    return _lax.psum(1, name)
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction: axis_types portability
-# ---------------------------------------------------------------------------
-
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-_MAKE_MESH_KWARGS = (frozenset(inspect.signature(jax.make_mesh).parameters)
-                     if hasattr(jax, "make_mesh") else frozenset())
+    """``lax.axis_size``: the static size of a named mesh axis."""
+    return lax.axis_size(name)
 
 
 def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where axis types exist, else ``None``."""
-    if AxisType is None:
-        return None
+    """``(AxisType.Auto,) * n``: the axis types every mesh here is built
+    with, so sharding propagation stays implicit inside ``jit``."""
     return (AxisType.Auto,) * n
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence[Any]] = None, axis_types=None):
-    """``jax.make_mesh`` that tolerates the ``axis_types`` kwarg appearing,
-    disappearing, or being mandatory-by-style across jax versions.  Falls
-    back to a hand-built ``jax.sharding.Mesh`` on jax without ``make_mesh``.
-    """
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with ``Auto`` axis types unless told otherwise."""
     axis_names = tuple(axis_names)
-    supports_types = "axis_types" in _MAKE_MESH_KWARGS
-    if axis_types is not None and not supports_types:
-        raise ValueError(
-            f"axis_types={axis_types!r} requested, but the installed jax "
-            f"{jax.__version__} has no axis-type support — drop the argument "
-            "(the default matches old-jax behavior) or upgrade jax")
-    if hasattr(jax, "make_mesh"):
-        kw: dict[str, Any] = {}
-        if devices is not None:
-            kw["devices"] = devices
-        if supports_types:
-            types = axis_types if axis_types is not None \
-                else auto_axis_types(len(axis_names))
-            if types is not None:
-                kw["axis_types"] = types
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    import numpy as np
-    n = math.prod(axis_shapes)
-    devs = devices if devices is not None else jax.devices()[:n]
-    if len(devs) < n:
-        raise ValueError(f"mesh {dict(zip(axis_names, axis_shapes))} needs "
-                         f"{n} devices, got {len(devs)}")
-    return jax.sharding.Mesh(np.asarray(devs).reshape(axis_shapes), axis_names)
+    kw: dict[str, Any] = {"axis_types": axis_types if axis_types is not None
+                          else auto_axis_types(len(axis_names))}
+    if devices is not None:
+        kw["devices"] = devices
+    return jax.make_mesh(tuple(axis_shapes), axis_names, **kw)
