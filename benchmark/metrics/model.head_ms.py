@@ -1,0 +1,13 @@
+"""model.head_ms: milliseconds per step that the busiest device spends in
+operations under the ``head`` scope (``models/transformer.py:_loss``): the
+final norm, the head product and the chunked cross-entropy, forward and
+backward (``benchmark/scopes.py``). Nothing to read where the program names
+no layer. Ops without a name of their own count where
+``scopes.instructions`` places them; the ``scopes`` line gives that part as
+``borrowed_ns``."""
+
+from benchmark.scopes import layer_ms
+
+
+def read(view):
+    return layer_ms(view, "head")

@@ -29,6 +29,14 @@ Fails (exit 1) on two kinds of bypass:
    elastic rebuild the tuning signature is unresolvable and ``scheme=
    "auto"`` silently degrades to the static fallback instead of re-tuning
    for the surviving topology.
+5. **Unscoped collectives** — a raw ``lax`` collective call
+   (``lax.psum(``, ``lax.all_gather(``, ``lax.ppermute(`` ...) in
+   ``src/repro/comm/``, ``src/repro/core/sync.py`` or ``src/repro/models/``
+   that does not go through ``repro.comm.primitives.scoped``, which names
+   the collective ``comm.<primitive>[<axes>]`` in the compiled module so a
+   profile can tell the on-node stage from the bridge.  No pragma excuses
+   it; text between double backticks (a docstring's ``lax.psum(x, axes)``)
+   is not code.
 
 Allowed everywhere:
   * ``VirtualCluster(...)`` construction (the substrate's topology spec is
@@ -52,7 +60,8 @@ import sys
 
 KWARG_RE = re.compile(r"\b(?:fast_axis|slow_axis)\s*=(?!=)")
 ALLOWED_LINE_RE = re.compile(r"\b(?:VirtualCluster|Communicator)\s*\(")
-RAW_RE = re.compile(r"\blax\.(?:psum|all_gather)\s*\(")
+# a call, or the primitive handed to ``scoped(lax.psum, ...)``
+RAW_RE = re.compile(r"\blax\.(?:psum|all_gather)\s*[(,]")
 RAW_PRAGMA = "raw-collective:"
 
 SCAN_ROOTS = ("src/repro", "examples")
@@ -64,6 +73,18 @@ RAW_ALLOWED_PATHS = (
     "src/repro/substrate/",          # compat shims wrap the primitives
     "src/repro/kernels/",            # Pallas bodies fuse their own wires
 )
+
+# every collective in these paths goes through ``primitives.scoped``
+UNSCOPED_RE = re.compile(
+    r"\blax\.(?:psum|pmean|pmax|pmin|all_gather|all_gather_invariant|"
+    r"psum_scatter|all_to_all|ragged_all_to_all|ppermute|pshuffle|"
+    r"pswapaxes|pbroadcast)\s*\(")
+SCOPED_PATHS = (
+    "src/repro/comm/",
+    "src/repro/core/sync.py",
+    "src/repro/models/",
+)
+LITERAL_RE = re.compile(r"``[^`]*``")
 
 # deprecated one-release shims: no NEW call sites outside the shim's own
 # module and the comm layer that implements the replacement
@@ -155,6 +176,22 @@ def raw_violations(repo: pathlib.Path) -> list[str]:
     return out
 
 
+def unscoped_violations(repo: pathlib.Path) -> list[str]:
+    """Raw ``lax`` collective calls in the comm layer, the sync primitives
+    and the models that bypass ``primitives.scoped``: the compiled module
+    would carry no ``comm.<primitive>[<axes>]`` scope for them."""
+    out: list[str] = []
+    for path, rel in _scan_files(repo):
+        if not any(rel.startswith(a) for a in SCOPED_PATHS):
+            continue
+        for lineno, line in enumerate(path.read_text().splitlines(),
+                                      start=1):
+            code = LITERAL_RE.sub("", line.split("#", 1)[0])
+            if UNSCOPED_RE.search(code):
+                out.append(f"{rel}:{lineno}: {line.strip()}")
+    return out
+
+
 def deprecated_violations(repo: pathlib.Path) -> list[str]:
     """Call sites of the deprecated ``optim.compression`` free functions
     outside ``repro/comm`` and ``repro/optim`` — those must migrate to the
@@ -188,7 +225,8 @@ def ctor_violations(repo: pathlib.Path) -> list[str]:
 
 def violations(repo: pathlib.Path) -> list[str]:
     return kwarg_violations(repo) + raw_violations(repo) \
-        + deprecated_violations(repo) + ctor_violations(repo)
+        + deprecated_violations(repo) + ctor_violations(repo) \
+        + unscoped_violations(repo)
 
 
 def main(argv=None) -> int:
@@ -199,6 +237,7 @@ def main(argv=None) -> int:
     bad_raw = raw_violations(repo)
     bad_deprecated = deprecated_violations(repo)
     bad_ctor = ctor_violations(repo)
+    bad_unscoped = unscoped_violations(repo)
     if bad_kwargs:
         print("api-surface check FAILED: raw fast_axis=/slow_axis= kwargs "
               "outside repro/comm — route these call sites through "
@@ -232,7 +271,15 @@ def main(argv=None) -> int:
               file=sys.stderr)
         for v in bad_ctor:
             print(f"  {v}", file=sys.stderr)
-    if bad_kwargs or bad_raw or bad_deprecated or bad_ctor:
+    if bad_unscoped:
+        print("api-surface check FAILED: raw lax collective calls in "
+              "repro/comm, repro/core/sync.py or repro/models — call "
+              "them through repro.comm.primitives.scoped(lax.<op>, x, "
+              "axes, ...) so the compiled module names them "
+              "comm.<op>[<axes>]:", file=sys.stderr)
+        for v in bad_unscoped:
+            print(f"  {v}", file=sys.stderr)
+    if bad_kwargs or bad_raw or bad_deprecated or bad_ctor or bad_unscoped:
         return 1
     print("api-surface check OK: all collective call sites go through "
           "repro.comm")

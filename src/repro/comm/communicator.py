@@ -397,7 +397,7 @@ class Communicator:
         if self.slow_axis is None:
             return x
         from jax import lax
-        return lax.psum(x, p._axes(self.slow_axis))
+        return p.scoped(lax.psum, x, p._axes(self.slow_axis))
 
     # -- step-graph optimizer -------------------------------------------------
     def record(self, *, table=None):

@@ -166,13 +166,14 @@ def pipelined_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
                                       if slow_axis is not None else 1)
 
     def stage_a(ck):
-        return lax.all_gather(ck, p._axes(fast_axis), axis=axis, tiled=True)
+        return p.scoped(lax.all_gather, ck, p._axes(fast_axis), axis=axis,
+                        tiled=True)
 
     def stage_b(region):
         if slow_axis is None:
             return region
-        return lax.all_gather(region, p._axes(slow_axis), axis=axis,
-                              tiled=True)
+        return p.scoped(lax.all_gather, region, p._axes(slow_axis), axis=axis,
+                        tiled=True)
 
     outs = two_phase_pipeline(chunks, stage_a=stage_a, stage_b=stage_b,
                               fast_axis=fast_axis, axis=axis)
@@ -201,11 +202,12 @@ def pipelined_broadcast(x: jax.Array, *, root: int = 0, fast_axis,
         lead = jnp.where((my_pod == my_pod_root)
                          & (me_fast == my_local_root), ck,
                          jnp.zeros_like(ck))
-        return lax.psum(lead, slow)      # bridge bcast (leaders nonzero)
+        # bridge bcast (leaders nonzero)
+        return p.scoped(lax.psum, lead, slow)
 
     def stage_b(lead):
-        return lax.psum(jnp.where(me_fast == my_local_root, lead,
-                                  jnp.zeros_like(lead)), fast)
+        lead = jnp.where(me_fast == my_local_root, lead, jnp.zeros_like(lead))
+        return p.scoped(lax.psum, lead, fast)
 
     outs = two_phase_pipeline(_split_blocked(x, axis, n_chunks),
                               stage_a=stage_a, stage_b=stage_b,
@@ -221,14 +223,14 @@ def pipelined_psum(x: jax.Array, *, fast_axis, slow_axis=None, axis: int = 0,
     bridge allreduce on shards + intra-pod allgather (stage b).
     """
     def stage_a(ck):
-        return lax.psum_scatter(ck, p._axes(fast_axis),
-                                scatter_dimension=axis, tiled=True)
+        return p.scoped(lax.psum_scatter, ck, p._axes(fast_axis),
+                        scatter_dimension=axis, tiled=True)
 
     def stage_b(shard):
         if slow_axis is not None:
-            shard = lax.psum(shard, p._axes(slow_axis))
-        return lax.all_gather(shard, p._axes(fast_axis), axis=axis,
-                              tiled=True)
+            shard = p.scoped(lax.psum, shard, p._axes(slow_axis))
+        return p.scoped(lax.all_gather, shard, p._axes(fast_axis), axis=axis,
+                        tiled=True)
 
     outs = two_phase_pipeline(_split_blocked(x, axis, n_chunks),
                               stage_a=stage_a, stage_b=stage_b,
@@ -257,12 +259,12 @@ def pipelined_reduce_scatter(x: jax.Array, *, fast_axis, slow_axis=None,
     def stage_a(ck):
         if slow_axis is None:
             return ck
-        return lax.psum_scatter(ck, p._axes(slow_axis),
-                                scatter_dimension=axis, tiled=True)
+        return p.scoped(lax.psum_scatter, ck, p._axes(slow_axis),
+                        scatter_dimension=axis, tiled=True)
 
     def stage_b(pod_slice):
-        return lax.psum_scatter(pod_slice, p._axes(fast_axis),
-                                scatter_dimension=axis, tiled=True)
+        return p.scoped(lax.psum_scatter, pod_slice, p._axes(fast_axis),
+                        scatter_dimension=axis, tiled=True)
 
     outs = two_phase_pipeline(chunks, stage_a=stage_a, stage_b=stage_b,
                               fast_axis=fast_axis, axis=axis)
@@ -349,8 +351,8 @@ def ag_matmul(x: jax.Array, w_shard: jax.Array, *, fast_axis,
     for j in range(n_chunks):
         shard_piece = fence.enter(j, lax.slice_in_dim(
             w_shard, j * piece, (j + 1) * piece, axis=0))
-        panel = lax.all_gather(shard_piece, p._axes(fast_axis), axis=0,
-                               tiled=True)              # (c*piece, N)
+        panel = p.scoped(lax.all_gather, shard_piece, p._axes(fast_axis),
+                         axis=0, tiled=True)              # (c*piece, N)
         xj = xr[..., :, j, :].reshape(lead + (c * piece,))
         prod = fence.exit(j, mm(xj, panel))
         acc = acc + prod.astype(jnp.float32)
@@ -393,8 +395,10 @@ def ag_matmul_q4(x: jax.Array, w_shard: jax.Array, *, fast_axis,
             w_shard, j * piece, (j + 1) * piece, axis=0))
         packed, scales = qz.quantize_q4(shard_piece, group=group)
         # raw-collective: the packed-int4 panel gather IS the wire format
-        gp = lax.all_gather(packed, p._axes(fast_axis), axis=0, tiled=True)
-        gs = lax.all_gather(scales, p._axes(fast_axis), axis=0, tiled=True)
+        gp = p.scoped(lax.all_gather, packed, p._axes(fast_axis), axis=0,
+                      tiled=True)
+        gs = p.scoped(lax.all_gather, scales, p._axes(fast_axis), axis=0,
+                      tiled=True)
         xj = xr[..., :, j, :].reshape(lead + (c * piece,))
         x2d = xj.reshape(-1, c * piece)
         if use_kernel:
@@ -428,7 +432,8 @@ def ag_matmul_rows(a_shard: jax.Array, b: jax.Array, *, fast_axis,
     for j in range(n_chunks):
         pj = fence.enter(j, lax.slice_in_dim(a_shard, j * piece,
                                              (j + 1) * piece, axis=0))
-        panel = lax.all_gather(pj, p._axes(fast_axis), axis=0, tiled=True)
+        panel = p.scoped(lax.all_gather, pj, p._axes(fast_axis), axis=0,
+                         tiled=True)
         outs.append(fence.exit(j, mm(panel, b)))
     return _merge_strided(outs, 0, blocks=c)
 
@@ -451,7 +456,7 @@ def matmul_rs(x: jax.Array, w: jax.Array, *, axis_name, scatter_dim: int = 0,
     outs = []
     for j, xc in enumerate(chunks):
         prod = mm(fence.enter(j, xc), w)
-        out = lax.psum_scatter(prod, p._axes(axis_name),
-                               scatter_dimension=scatter_dim, tiled=True)
+        out = p.scoped(lax.psum_scatter, prod, p._axes(axis_name),
+                       scatter_dimension=scatter_dim, tiled=True)
         outs.append(fence.exit(j, out))
     return _merge_blocked(outs, scatter_dim)

@@ -42,6 +42,19 @@ def _axes(ax) -> tuple:
     return tuple(ax) if isinstance(ax, (tuple, list)) else (ax,)
 
 
+def scoped(op, x, axes, *args, **kwargs):
+    """Run the ``lax`` collective ``op`` over the mesh ``axes`` inside the
+    named scope ``comm.<op>[<axes>]``, the axis names in call order
+    (``comm.all_gather[data]``, ``comm.psum[pod,data]``), so that a profile
+    can tell the on-node stage from the bridge.  The scope labels the
+    compiled instructions' metadata only; ``axes`` reaches ``op`` as given.
+    Every collective of ``repro.comm``, ``core.sync`` and ``repro.models``
+    goes through here (``scripts/check_api_surface.py``)."""
+    names = ",".join(str(a) for a in _axes(axes))
+    with jax.named_scope(f"comm.{op.__name__}[{names}]"):
+        return op(x, axes, *args, **kwargs)
+
+
 def axis_size(ax) -> int:
     s = 1
     for a in _axes(ax):
@@ -66,17 +79,19 @@ def naive_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
                      axis: int = 0) -> jax.Array:
     """Pure-MPI analogue: one flat all-gather; full private copy per chip."""
     names = (_axes(slow_axis) if slow_axis else ()) + _axes(fast_axis)
-    return lax.all_gather(x, names, axis=axis, tiled=True)
+    return scoped(lax.all_gather, x, names, axis=axis, tiled=True)
 
 
 def hier_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
                     axis: int = 0) -> jax.Array:
     """Two-phase allgather: intra-pod gather, then bridge exchange of whole
     node regions (leaders' ``MPI_Allgatherv`` in the regular case)."""
-    node_region = lax.all_gather(x, _axes(fast_axis), axis=axis, tiled=True)
+    node_region = scoped(lax.all_gather, x, _axes(fast_axis), axis=axis,
+                         tiled=True)
     if slow_axis is None:
         return node_region
-    return lax.all_gather(node_region, _axes(slow_axis), axis=axis, tiled=True)
+    return scoped(lax.all_gather, node_region, _axes(slow_axis), axis=axis,
+                  tiled=True)
 
 
 def shared_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
@@ -93,12 +108,13 @@ def shared_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
     """
     if slow_axis is None:
         return x  # single node: partition already in the shared window
-    return lax.all_gather(x, _axes(slow_axis), axis=axis, tiled=True)
+    return scoped(lax.all_gather, x, _axes(slow_axis), axis=axis, tiled=True)
 
 
 def shared_read(shard: jax.Array, *, fast_axis, axis: int = 0) -> jax.Array:
     """Load the pod-shared buffer (an intra-pod gather at use time)."""
-    return lax.all_gather(shard, _axes(fast_axis), axis=axis, tiled=True)
+    return scoped(lax.all_gather, shard, _axes(fast_axis), axis=axis,
+                  tiled=True)
 
 
 def shared_to_rank_order(full: jax.Array, *, num_pods: int,
@@ -127,8 +143,9 @@ def shared_all_gather_v(x_padded: jax.Array, valid: jax.Array, *,
     dimension has extent 1."""
     if slow_axis is None:
         return jnp.expand_dims(x_padded, axis), valid[None]
-    blocks = lax.all_gather(x_padded, _axes(slow_axis), axis=axis, tiled=False)
-    counts = lax.all_gather(valid, _axes(slow_axis), tiled=False)
+    blocks = scoped(lax.all_gather, x_padded, _axes(slow_axis), axis=axis,
+                    tiled=False)
+    counts = scoped(lax.all_gather, valid, _axes(slow_axis), tiled=False)
     return blocks, counts
 
 
@@ -142,7 +159,7 @@ def naive_broadcast(x: jax.Array, *, root: int, fast_axis, slow_axis=None
     names = (_axes(slow_axis) if slow_axis else ()) + _axes(fast_axis)
     me = axis_index(names)
     contrib = jnp.where(me == root, x, jnp.zeros_like(x))
-    return lax.psum(contrib, names)
+    return scoped(lax.psum, contrib, names)
 
 
 def _flat_root(root, fast_axis, slow_axis):
@@ -179,11 +196,12 @@ def hier_broadcast(x: jax.Array, *, root: int | None = None, fast_axis,
         my_pod = axis_index(slow)
         lead = jnp.where((my_pod == my_pod_root) & (me_fast == my_local_root),
                          x, jnp.zeros_like(x))
-        lead = lax.psum(lead, slow)  # bridge bcast (only leaders nonzero)
+        # bridge bcast (only leaders nonzero)
+        lead = scoped(lax.psum, lead, slow)
     else:
         lead = jnp.where(me_fast == my_local_root, x, jnp.zeros_like(x))
-    return lax.psum(jnp.where(me_fast == my_local_root, lead,
-                              jnp.zeros_like(lead)), fast)
+    lead = jnp.where(me_fast == my_local_root, lead, jnp.zeros_like(lead))
+    return scoped(lax.psum, lead, fast)
 
 
 def shared_broadcast(x: jax.Array, *, root: int | None = None, fast_axis,
@@ -202,14 +220,14 @@ def shared_broadcast(x: jax.Array, *, root: int | None = None, fast_axis,
     fast = _axes(fast_axis)
     me_fast = axis_index(fast)
     contrib = jnp.where(me_fast == my_local_root, x, jnp.zeros_like(x))
-    shard = lax.psum_scatter(contrib, fast, scatter_dimension=axis,
-                             tiled=True)
+    shard = scoped(lax.psum_scatter, contrib, fast, scatter_dimension=axis,
+                   tiled=True)
     if slow_axis is None:
         return shard
     slow = _axes(slow_axis)
     my_pod = axis_index(slow)
     shard = jnp.where(my_pod == my_pod_root, shard, jnp.zeros_like(shard))
-    return lax.psum(shard, slow)
+    return scoped(lax.psum, shard, slow)
 
 
 # ---------------------------------------------------------------------------
@@ -219,18 +237,19 @@ def shared_broadcast(x: jax.Array, *, root: int | None = None, fast_axis,
 def naive_psum(x: jax.Array, *, fast_axis, slow_axis=None) -> jax.Array:
     """Flat allreduce; result replicated per chip."""
     names = (_axes(slow_axis) if slow_axis else ()) + _axes(fast_axis)
-    return lax.psum(x, names)
+    return scoped(lax.psum, x, names)
 
 
 def hier_psum(x: jax.Array, *, fast_axis, slow_axis=None, axis: int = 0
               ) -> jax.Array:
     """Two-phase allreduce to full replication: intra-pod reduce-scatter,
     bridge allreduce on shards (multi-leader), intra-pod allgather."""
-    shard = lax.psum_scatter(x, _axes(fast_axis), scatter_dimension=axis,
-                             tiled=True)
+    shard = scoped(lax.psum_scatter, x, _axes(fast_axis),
+                   scatter_dimension=axis, tiled=True)
     if slow_axis is not None:
-        shard = lax.psum(shard, _axes(slow_axis))
-    return lax.all_gather(shard, _axes(fast_axis), axis=axis, tiled=True)
+        shard = scoped(lax.psum, shard, _axes(slow_axis))
+    return scoped(lax.all_gather, shard, _axes(fast_axis), axis=axis,
+                  tiled=True)
 
 
 def shared_psum_scatter(x: jax.Array, *, fast_axis, slow_axis=None,
@@ -239,10 +258,10 @@ def shared_psum_scatter(x: jax.Array, *, fast_axis, slow_axis=None,
     over ``fast_axis``.  This is the gradient-reduction of hier train mode:
     children write partial sums (intra-pod RS), leaders exchange on the
     bridge, the reduced value never gets replicated."""
-    shard = lax.psum_scatter(x, _axes(fast_axis), scatter_dimension=axis,
-                             tiled=True)
+    shard = scoped(lax.psum_scatter, x, _axes(fast_axis),
+                   scatter_dimension=axis, tiled=True)
     if slow_axis is not None:
-        shard = lax.psum(shard, _axes(slow_axis))
+        shard = scoped(lax.psum, shard, _axes(slow_axis))
     return shard
 
 
@@ -251,7 +270,8 @@ def naive_reduce_scatter(x: jax.Array, *, fast_axis, slow_axis=None,
     """Flat MPI_Reduce_scatter analogue: every rank ends with its 1/R slice
     of the global sum, rank-major (pod, chip) order."""
     names = (_axes(slow_axis) if slow_axis else ()) + _axes(fast_axis)
-    return lax.psum_scatter(x, names, scatter_dimension=axis, tiled=True)
+    return scoped(lax.psum_scatter, x, names, scatter_dimension=axis,
+                  tiled=True)
 
 
 # ---------------------------------------------------------------------------
@@ -264,8 +284,8 @@ def naive_all_to_all(x: jax.Array, *, fast_axis, slow_axis=None,
     buffer along ``axis`` is R equal chunks in flat (pod, chip) rank order;
     chunk *s* goes to rank *s* and the result is ordered by source rank."""
     names = (_axes(slow_axis) if slow_axis else ()) + _axes(fast_axis)
-    return lax.all_to_all(x, names, split_axis=axis, concat_axis=axis,
-                          tiled=True)
+    return scoped(lax.all_to_all, x, names, split_axis=axis, concat_axis=axis,
+                  tiled=True)
 
 
 def hier_all_to_all(x: jax.Array, *, fast_axis, slow_axis=None,
@@ -280,8 +300,8 @@ def hier_all_to_all(x: jax.Array, *, fast_axis, slow_axis=None,
     """
     fast = _axes(fast_axis)
     if slow_axis is not None:
-        x = lax.all_to_all(x, _axes(slow_axis), split_axis=axis,
-                           concat_axis=axis, tiled=True)
+        x = scoped(lax.all_to_all, x, _axes(slow_axis), split_axis=axis,
+                   concat_axis=axis, tiled=True)
     pods = axis_size(slow_axis) if slow_axis is not None else 1
     fast_sizes = tuple(_axis_size_one(a) for a in fast)
     chips = 1
@@ -296,7 +316,7 @@ def hier_all_to_all(x: jax.Array, *, fast_axis, slow_axis=None,
     y = moved.reshape((pods,) + fast_sizes + (chunk,) + moved.shape[1:])
     for i, a in enumerate(fast):
         if fast_sizes[i] > 1:
-            y = lax.all_to_all(y, a, split_axis=1 + i, concat_axis=1 + i,
-                               tiled=False)
+            y = scoped(lax.all_to_all, y, a, split_axis=1 + i,
+                       concat_axis=1 + i, tiled=False)
     y = y.reshape((n,) + moved.shape[1:])
     return jnp.moveaxis(y, 0, axis)

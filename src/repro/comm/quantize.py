@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .primitives import _axes, axis_index
+from .primitives import _axes, axis_index, scoped
 from repro.substrate.compat import axis_size
 
 DEFAULT_BLOCK = 256
@@ -85,7 +85,7 @@ def block_quantize(x: jax.Array, *, block: int = DEFAULT_BLOCK,
     blocks, size, block_eff = _to_blocks(x, block)
     amax = jnp.max(jnp.abs(blocks), axis=1)
     if shared_axes:
-        amax = lax.pmax(amax, _axes(shared_axes))
+        amax = scoped(lax.pmax, amax, _axes(shared_axes))
     scale = jnp.maximum(amax, _EPS) / qmax
     scaled = blocks / scale[:, None]
     if stochastic:
@@ -214,7 +214,7 @@ def q8_psum_flat(x: jax.Array, axes, *, block: int = DEFAULT_BLOCK,
                 lax.bitcast_convert_type(scale, jnp.uint8).reshape(-1)])
             length = wire.shape[0]
             # raw-collective: the fused u8 gather IS the scheme body
-            g = lax.all_gather(wire, axes, axis=0, tiled=True) \
+            g = scoped(lax.all_gather, wire, axes, axis=0, tiled=True) \
                 .reshape(p, length)
             codes = lax.bitcast_convert_type(
                 g[:, :length - 4 * nb], jnp.int8).reshape(p, *q.shape)
@@ -234,7 +234,7 @@ def q8_psum_flat(x: jax.Array, axes, *, block: int = DEFAULT_BLOCK,
                                     key=key)
     local = block_dequantize(q, scale, meta, x.shape, jnp.float32)
     # raw-collective: int16 wire sum IS the scheme body (registry q8_hier)
-    tot16 = lax.psum(q.astype(jnp.int16), axes)
+    tot16 = scoped(lax.psum, q.astype(jnp.int16), axes)
     total = _from_blocks(tot16.astype(jnp.float32) * scale[:, None],
                          meta[0], x.shape, jnp.float32)
     out = total.astype(x.dtype)
@@ -263,7 +263,7 @@ def qbf16_psum_flat(x: jax.Array, axes, *,
     if axes:
         codes = lax.bitcast_convert_type(wire, jnp.uint16)
         # raw-collective: the u16 bridge exchange IS the scheme body
-        g = lax.all_gather(codes, axes, axis=0, tiled=False)
+        g = scoped(lax.all_gather, codes, axes, axis=0, tiled=False)
         tot = lax.bitcast_convert_type(g, jnp.bfloat16) \
             .astype(jnp.float32).sum(axis=0)
     else:
@@ -286,10 +286,11 @@ def _bridge_psum(x, fast_axis, slow_axis, axis, bridge_core, err):
     fast = _axes(fast_axis)
     if slow_axis is None:
         return bridge_core(x, fast, err)
-    shard = lax.psum_scatter(x, fast, scatter_dimension=axis, tiled=True)
+    shard = scoped(lax.psum_scatter, x, fast, scatter_dimension=axis,
+                   tiled=True)
     res = bridge_core(shard, _axes(slow_axis), err)
     total, new_err = res if err is not None else (res, None)
-    out = lax.all_gather(total, fast, axis=axis, tiled=True)
+    out = scoped(lax.all_gather, total, fast, axis=axis, tiled=True)
     if err is None:
         return out
     return out, new_err
@@ -319,8 +320,8 @@ def _bridge_gather_blocks(q_flat, scale, slow_axis):
     """Gather int8 codes + f32 scales across the bridge (untiled)."""
     slow = _axes(slow_axis)
     # raw-collective: the compressed bridge exchange IS the scheme body
-    gq = lax.all_gather(q_flat, slow, axis=0, tiled=False)
-    gs = lax.all_gather(scale, slow, axis=0, tiled=False)
+    gq = scoped(lax.all_gather, q_flat, slow, axis=0, tiled=False)
+    gs = scoped(lax.all_gather, scale, slow, axis=0, tiled=False)
     return gq, gs
 
 
@@ -348,7 +349,7 @@ def q8_hier_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
     exactly afterwards.
     """
     fast = _axes(fast_axis)
-    node = lax.all_gather(x, fast, axis=axis, tiled=True)
+    node = scoped(lax.all_gather, x, fast, axis=axis, tiled=True)
     if slow_axis is None:
         return node
     q, scale, meta = block_quantize(node, block=block, qmax=Q8_MAX)
@@ -365,7 +366,7 @@ def qbf16_hier_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
                           axis: int = 0):
     """Hier allgather with a bf16 bridge (scale-free truncation)."""
     fast = _axes(fast_axis)
-    node = lax.all_gather(x, fast, axis=axis, tiled=True)
+    node = scoped(lax.all_gather, x, fast, axis=axis, tiled=True)
     if slow_axis is None:
         return node
     # the wire carries bitcast u16: an integer gather lowers natively
@@ -373,7 +374,8 @@ def qbf16_hier_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
     # XLA's CPU bf16 normalization (silently doubling the wire)
     codes = lax.bitcast_convert_type(node.astype(jnp.bfloat16), jnp.uint16)
     # raw-collective: the compressed bridge exchange IS the scheme body
-    gw = lax.all_gather(codes, _axes(slow_axis), axis=axis, tiled=True)
+    gw = scoped(lax.all_gather, codes, _axes(slow_axis), axis=axis,
+                tiled=True)
     wide = lax.bitcast_convert_type(gw, jnp.bfloat16)
     out = wide.astype(jnp.float32).astype(x.dtype)
     return _restore_own_region(out, node, slow_axis, axis)
@@ -396,8 +398,8 @@ def q4_shared_all_gather(x: jax.Array, *, fast_axis, slow_axis=None,
     packed = pack_int4(q.reshape(-1).reshape(-1, 2)).reshape(-1)
     slow = _axes(slow_axis)
     # raw-collective: the packed-int4 bridge exchange IS the scheme body
-    gp = lax.all_gather(packed, slow, axis=0, tiled=False)
-    gs = lax.all_gather(scale, slow, axis=0, tiled=False)
+    gp = scoped(lax.all_gather, packed, slow, axis=0, tiled=False)
+    gs = scoped(lax.all_gather, scale, slow, axis=0, tiled=False)
     n_pods = gp.shape[0]
     codes = unpack_int4(gp).reshape(n_pods, *q.shape).astype(jnp.float32)
     deq = (codes * gs[:, :, None]).reshape(n_pods, -1)[:, :meta[0]]

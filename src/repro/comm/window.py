@@ -70,8 +70,8 @@ class SharedWindow:
     def accumulate(self, x: jax.Array) -> "SharedWindow":
         """Reduce partial contributions from every on-node rank into the
         window shards (intra-pod reduce-scatter — the gradient store)."""
-        shard = lax.psum_scatter(x, p._axes(self.comm.fast_axis),
-                                 scatter_dimension=self.axis, tiled=True)
+        shard = p.scoped(lax.psum_scatter, x, p._axes(self.comm.fast_axis),
+                         scatter_dimension=self.axis, tiled=True)
         return dataclasses.replace(self, shard=shard, dirty=True)
 
     # -- synchronization ------------------------------------------------------
@@ -165,5 +165,6 @@ def window_scatter(x: jax.Array, dim: Optional[int], fast_axis) -> jax.Array:
     (``dim=None``: plain psum of the replicated tensor)."""
     axes = p._axes(fast_axis)
     if dim is None:
-        return lax.psum(x, axes)
-    return lax.psum_scatter(x, axes, scatter_dimension=dim, tiled=True)
+        return p.scoped(lax.psum, x, axes)
+    return p.scoped(lax.psum_scatter, x, axes, scatter_dimension=dim,
+                    tiled=True)

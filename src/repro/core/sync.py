@@ -15,14 +15,15 @@ from jax import lax
 
 from repro.substrate.compat import axis_size as _axis_size_one
 
-from repro.comm.primitives import _axes, axis_index
+from repro.comm.primitives import _axes, axis_index, scoped
 
 
 def barrier(token: jax.Array, axis) -> jax.Array:
     """Heavy-weight barrier: a scalar allreduce over ``axis`` (the paper's
     ``MPI_Barrier(sharedmemComm)``).  Returns a value data-dependent on every
     participant — thread it into downstream computation to enforce ordering."""
-    return lax.psum(token, _axes(axis))  # raw-collective: the barrier primitive itself
+    # raw-collective: the barrier primitive itself
+    return scoped(lax.psum, token, _axes(axis))
 
 
 def flag_chain(token: jax.Array, axis) -> jax.Array:
@@ -34,7 +35,7 @@ def flag_chain(token: jax.Array, axis) -> jax.Array:
     for a in axes:
         n = _axis_size_one(a)
         perm = [(i, (i + 1) % n) for i in range(n)]
-        out = lax.ppermute(out, a, perm)
+        out = scoped(lax.ppermute, out, a, perm)
     return out
 
 
@@ -44,4 +45,4 @@ def leader_flag(token: jax.Array, *, fast_axis) -> jax.Array:
     me = axis_index(fast_axis)
     contrib = jnp.where(me == 0, jnp.zeros_like(token), token)
     # raw-collective: the barrier primitive itself
-    return lax.psum(contrib, _axes(fast_axis))
+    return scoped(lax.psum, contrib, _axes(fast_axis))

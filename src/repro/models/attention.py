@@ -121,6 +121,7 @@ def attn_flops(B: int, Tq: int, Tkv: int, H: int, hd: int, *,
 # Train/prefill block
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def attn_block(x_sp: jax.Array, p: dict, meta: dict, ctx: ParallelCtx, cfg, *,
                mode: str, window: Optional[int], t_offset: int = 0,
                return_kv: bool = False):

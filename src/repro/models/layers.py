@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.comm.primitives import scoped
 from repro.models.parallel import ParallelCtx
 
 
@@ -179,8 +180,8 @@ def decode_logits(x: jax.Array, unemb: jax.Array, ctx: ParallelCtx, *,
     if softcap:
         logits = softcap * jnp.tanh(logits / softcap)
     if ctx.tp_axis:
-        logits = lax.all_gather(  # raw-collective: flat tp fast path
-            logits, ctx.tp_axis, axis=-1, tiled=True)
+        logits = scoped(lax.all_gather,  # raw-collective: flat tp fast path
+                        logits, ctx.tp_axis, axis=-1, tiled=True)
     return logits
 
 
@@ -188,6 +189,7 @@ def decode_logits(x: jax.Array, unemb: jax.Array, ctx: ParallelCtx, *,
 # Dense FFN (Megatron-SP: AG tokens -> col/row parallel -> RS tokens)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mlp")
 def ffn(x_sp: jax.Array, p: dict, meta: dict, ctx: ParallelCtx, *,
         act: str, eps: float) -> jax.Array:
     # issue every window read up front (issue-early discipline: the weight

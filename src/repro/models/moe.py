@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.comm.primitives import scoped
 from repro.models.layers import activation, rms_norm
 from repro.models.parallel import ParallelCtx
 
@@ -89,8 +90,8 @@ def moe_block(x_sp: jax.Array, p: dict, meta: dict, ctx: ParallelCtx, cfg, *,
         # data axis (hier; expert dff is stored data-sharded), or keep local
         # (naive; weights fully replicated).
         if ctx.mode == "hier" and ctx.fsdp_axes:
-            hg = lax.all_gather(  # raw-collective: expert dispatch
-                h, ctx.fsdp_axes, axis=0, tiled=True)
+            hg = scoped(lax.all_gather,  # raw-collective: expert dispatch
+                        h, ctx.fsdp_axes, axis=0, tiled=True)
         else:
             hg = h
     else:
@@ -129,10 +130,9 @@ def moe_block(x_sp: jax.Array, p: dict, meta: dict, ctx: ParallelCtx, cfg, *,
     if serve:
         if ctx.mode == "hier" and ctx.fsdp_axes:
             # raw-collective: expert-dispatch fast path, both arms
-            y = (lax.psum(y, (ctx.tp_axis,)  # raw-collective: above
-                          + tuple(ctx.fsdp_axes))
-                 if ctx.tp_axis else
-                 lax.psum(y, ctx.fsdp_axes))  # raw-collective: above
+            axes = ((ctx.tp_axis,) if ctx.tp_axis else ()) \
+                + tuple(ctx.fsdp_axes)
+            y = scoped(lax.psum, y, axes)  # raw-collective: above
             b_loc = x_sp.shape[0]
             r = lax.axis_index(ctx.fsdp_axes[0])
             y = lax.dynamic_slice_in_dim(y, r * b_loc, b_loc, 0)

@@ -33,6 +33,7 @@ from jax import lax
 
 from repro.comm import AsyncCollectiveHandle, Communicator
 from repro.comm.handle import _ordered
+from repro.comm.primitives import scoped
 from repro.comm.window import WindowEpochError
 
 
@@ -323,21 +324,23 @@ class ParallelCtx:
         if not self.tp_axis:
             return x
         from jax.ad_checkpoint import checkpoint_name
-        out = lax.all_gather(  # raw-collective: ag_tokens tp fast path (allowlisted)
-            x, self.tp_axis, axis=dim, tiled=True)
+        # raw-collective: ag_tokens tp fast path
+        out = scoped(lax.all_gather,
+                     x, self.tp_axis, axis=dim, tiled=True)
         return checkpoint_name(out, "ag_out")
 
     def rs_tokens(self, x: jax.Array, dim: int = 1) -> jax.Array:
         """Sequence-parallel reduce-scatter: partial (B, T, d) -> (B, T/tp, d)."""
         if not self.tp_axis:
             return x
-        return lax.psum_scatter(x, self.tp_axis, scatter_dimension=dim,
-                                tiled=True)
+        return scoped(lax.psum_scatter, x, self.tp_axis,
+                      scatter_dimension=dim, tiled=True)
 
     def psum_tp(self, x: jax.Array) -> jax.Array:
         if not self.tp_axis:
             return x
-        return lax.psum(x, self.tp_axis)  # raw-collective: psum_tp fast path
+        # raw-collective: psum_tp fast path
+        return scoped(lax.psum, x, self.tp_axis)
 
     def group_all_gather(self, x: jax.Array, *, group: int, dim: int
                          ) -> jax.Array:
@@ -347,16 +350,17 @@ class ParallelCtx:
             return x
         n = self.tp
         groups = [list(range(s, s + group)) for s in range(0, n, group)]
-        return lax.all_gather(  # raw-collective: grouped tp fast path
-            x, self.tp_axis, axis=dim, tiled=True, axis_index_groups=groups)
+        return scoped(lax.all_gather,  # raw-collective: grouped tp fast path
+                      x, self.tp_axis, axis=dim, tiled=True,
+                      axis_index_groups=groups)
 
     def group_psum(self, x: jax.Array, *, group: int) -> jax.Array:
         if not self.tp_axis or group == 1:
             return x
         n = self.tp
         groups = [list(range(s, s + group)) for s in range(0, n, group)]
-        return lax.psum(  # raw-collective: grouped tp fast path
-            x, self.tp_axis, axis_index_groups=groups)
+        return scoped(lax.psum,  # raw-collective: grouped tp fast path
+                      x, self.tp_axis, axis_index_groups=groups)
 
     def pmax_tp(self, x: jax.Array) -> jax.Array:
         """Cross-shard max.  Implemented as all_gather+max rather than pmax:
@@ -365,7 +369,7 @@ class ParallelCtx:
         if not self.tp_axis:
             return x
         # raw-collective: pmax_tp tp fast path
-        g = lax.all_gather(x, self.tp_axis)   # (tp, ...)
+        g = scoped(lax.all_gather, x, self.tp_axis)   # (tp, ...)
         return jnp.max(g, axis=0)
 
     # ---- sizes ---------------------------------------------------------------

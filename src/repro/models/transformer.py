@@ -278,6 +278,7 @@ def _block_decode(kind: str, x, p, mt, state, ctx, cfg, *, pos):
 # Embedding / loss glue
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed_sp(cfg, ctx, defs, params, batch, *, T: int):
     """Build the sequence-parallel input embedding (B, T/tp, d) plus FULL
     (labels, mask) of shape (B, T) — the streamed loss consumes full-T
@@ -418,11 +419,12 @@ def _loss(cfg, ctx, defs, params, batch, *, unroll: int = 1):
     x, labels, mask = _embed_sp(cfg, ctx, defs, params, batch, T=T)
     x, _ = _scan_units(cfg, ctx, defs, params, x, unroll=unroll)
     x, _ = _rem_blocks(cfg, ctx, defs, params, x)
-    x = rms_norm(x, ctx.gather_w(params["final_ln"],
-                                 defs["final_ln"].fsdp_dim), cfg.norm_eps)
-    w_un = _unembed_weight(cfg, ctx, defs, params)
-    return unembed_xent(x, labels, mask, w_un, ctx, chunk=XENT_CHUNK,
-                        softcap=cfg.logit_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, ctx.gather_w(params["final_ln"],
+                                     defs["final_ln"].fsdp_dim), cfg.norm_eps)
+        w_un = _unembed_weight(cfg, ctx, defs, params)
+        return unembed_xent(x, labels, mask, w_un, ctx, chunk=XENT_CHUNK,
+                            softcap=cfg.logit_softcap)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.comm.primitives import scoped
 from repro.models.layers import rms_norm
 from repro.models.parallel import ParallelCtx
 
@@ -310,7 +311,7 @@ def slstm_block(x_sp, p, meta, ctx: ParallelCtx, cfg, *,
             full = lax.dynamic_update_slice_in_dim(
                 full, s * jnp.asarray(primary, s.dtype), seq_idx * bs, 0)
             # raw-collective: flat tp fast path (one group, one schedule)
-            return lax.psum(full, ctx.tp_axis)
+            return scoped(lax.psum, full, ctx.tp_axis)
         new_state = dict(zip(("h", "c", "n", "m"), map(widen, final)))
 
     w_out = ctx.gather_w(p["w_out"], meta["w_out"].fsdp_dim)  # (d, d)

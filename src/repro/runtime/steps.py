@@ -165,29 +165,32 @@ def make_train_step(cfg: ModelConfig, topo: MeshTopology, mesh, *,
             cnt_g = world.allreduce(cnt, result="replicated")
             grads = ctx.reduce_grads(grads, meta_leaves, compress=compress,
                                      precision=grad_precision)
-        grads = jax.tree.map(lambda g: g / cnt_g, grads)
+        with jax.named_scope("optimizer"):
+            grads = jax.tree.map(lambda g: g / cnt_g, grads)
 
-        # global grad norm: each leaf is tiled over the axes it is sharded on
-        # and replicated over the rest of the node tier — weight the square
-        # by 1/replication so the reduction counts every element exactly
-        # once.  Node-local: grads are pod-identical after the bridge.
-        gsq = jnp.float32(0.0)
-        for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
-            repl = 1.0
-            if meta.tp_dim is None and "model" in topo.axis_sizes:
-                repl *= topo.size("model")
-            data_sharded = (ctx.mode == "hier" and meta.fsdp_dim is not None)
-            if not data_sharded and "data" in topo.axis_sizes:
-                repl *= topo.size("data")
-            gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
-        gsq = node.allreduce(gsq, result="replicated")
-        gnorm = jnp.sqrt(gsq)
-        scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
-        grads = jax.tree.map(lambda g: g * scale, grads)
+            # global grad norm: each leaf is tiled over the axes it is
+            # sharded on and replicated over the rest of the node tier —
+            # weight the square by 1/replication so the reduction counts
+            # every element exactly once.  Node-local: grads are
+            # pod-identical after the bridge.
+            gsq = jnp.float32(0.0)
+            for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
+                repl = 1.0
+                if meta.tp_dim is None and "model" in topo.axis_sizes:
+                    repl *= topo.size("model")
+                data_sharded = (ctx.mode == "hier"
+                                and meta.fsdp_dim is not None)
+                if not data_sharded and "data" in topo.axis_sizes:
+                    repl *= topo.size("data")
+                gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
+            gsq = node.allreduce(gsq, result="replicated")
+            gnorm = jnp.sqrt(gsq)
+            scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
+            grads = jax.tree.map(lambda g: g * scale, grads)
 
-        new_params, new_m, new_v = adamw_update(
-            params, grads, state["m"], state["v"], state["step"] + 1,
-            lr=lr, weight_decay=weight_decay)
+            new_params, new_m, new_v = adamw_update(
+                params, grads, state["m"], state["v"], state["step"] + 1,
+                lr=lr, weight_decay=weight_decay)
         new_state = {"params": new_params, "m": new_m, "v": new_v,
                      "step": state["step"] + 1}
         metrics = {"loss": loss_g / cnt_g, "gnorm": gnorm, "tokens": cnt_g}
@@ -314,22 +317,23 @@ def make_cluster_train_step(cfg: ModelConfig, vc, *, mode: str = "hier",
             loss_g = world.allreduce(loss_sum, result="replicated")
             cnt_g = world.allreduce(cnt, result="replicated")
             grads = ctx.reduce_grads(grads, meta_leaves)
-        grads = jax.tree.map(lambda g: g / cnt_g, grads)
-        gsq = jnp.float32(0.0)
-        for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
-            repl = 1.0
-            if meta.tp_dim is None and ctx.tp_axis:
-                repl *= ctx.tp
-            if meta.fsdp_dim is None or ctx.mode != "hier":
-                repl *= data
-            gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
-        gsq = node.allreduce(gsq, result="replicated")
-        gnorm = jnp.sqrt(gsq)
-        scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
-        grads = jax.tree.map(lambda g: g * scale, grads)
-        new_params, new_m, new_v = adamw_update(
-            params, grads, state["m"], state["v"], state["step"] + 1,
-            lr=lr, weight_decay=weight_decay)
+        with jax.named_scope("optimizer"):
+            grads = jax.tree.map(lambda g: g / cnt_g, grads)
+            gsq = jnp.float32(0.0)
+            for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
+                repl = 1.0
+                if meta.tp_dim is None and ctx.tp_axis:
+                    repl *= ctx.tp
+                if meta.fsdp_dim is None or ctx.mode != "hier":
+                    repl *= data
+                gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
+            gsq = node.allreduce(gsq, result="replicated")
+            gnorm = jnp.sqrt(gsq)
+            scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
+            grads = jax.tree.map(lambda g: g * scale, grads)
+            new_params, new_m, new_v = adamw_update(
+                params, grads, state["m"], state["v"], state["step"] + 1,
+                lr=lr, weight_decay=weight_decay)
         new_state = {"params": new_params, "m": new_m, "v": new_v,
                      "step": state["step"] + 1}
         metrics = {"loss": loss_g / cnt_g, "gnorm": gnorm, "tokens": cnt_g}

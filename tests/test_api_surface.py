@@ -6,6 +6,8 @@ no module outside ``repro/comm`` (and the deprecated shim) may pass raw
 import pathlib
 import sys
 
+import pytest
+
 REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO / "scripts"))
 
@@ -121,8 +123,9 @@ def test_raw_collective_pragma_allows(tmp_path):
     ok.mkdir(parents=True)
     (ok / "fine.py").write_text(
         "from jax import lax\n"
+        "from repro.comm.primitives import scoped\n"
         "def f(x):\n"
-        "    return lax.psum(x, 'tp')  # raw-collective: tp fast path\n")
+        "    return scoped(lax.psum, x, 'tp')  # raw-collective: tp path\n")
     assert check_api_surface.raw_violations(tmp_path) == []
     assert check_api_surface.main([str(tmp_path)]) == 0
 
@@ -161,3 +164,63 @@ def test_raw_collective_pragma_on_preceding_line_allows(tmp_path):
         "    return lax.psum(x, 'data')   # two lines below the pragma:\n")
     hits = check_api_surface.raw_violations(tmp_path)
     assert len(hits) == 1 and "long.py:6" in hits[0]
+
+
+# ---- collectives named through primitives.scoped ----------------------------
+def test_scoped_collective_caught_without_pragma(tmp_path):
+    """Handing the primitive to ``scoped`` outside the comm layers still
+    bypasses the Communicator: the raw rule sees it."""
+    bad = tmp_path / "src" / "repro" / "runtime"
+    bad.mkdir(parents=True)
+    (bad / "rogue.py").write_text(
+        "from jax import lax\n"
+        "from repro.comm.primitives import scoped\n"
+        "def f(x):\n"
+        "    return scoped(lax.psum, x, 'data')\n")
+    hits = check_api_surface.raw_violations(tmp_path)
+    assert len(hits) == 1 and "rogue.py:4" in hits[0]
+
+
+@pytest.mark.parametrize("rel", ["src/repro/comm/impl.py",
+                                 "src/repro/core/sync.py",
+                                 "src/repro/models/parallel.py",
+                                 "src/repro/models/layers.py"])
+def test_unscoped_collective_caught(tmp_path, rel):
+    """A raw ``lax`` collective call where the program's collectives live
+    fails whatever its pragma: the compiled module would not name it."""
+    path = tmp_path / rel
+    path.parent.mkdir(parents=True)
+    path.write_text(
+        "from jax import lax\n"
+        "def f(x, perm):\n"
+        "    return lax.ppermute(x, 'data', perm)  # raw-collective: ring\n"
+        "def g(x):\n"
+        "    return lax.psum_scatter(x, 'data', tiled=True)\n")
+    hits = check_api_surface.unscoped_violations(tmp_path)
+    assert len(hits) == 2
+    assert "py:3" in hits[0] and "py:5" in hits[1]
+    assert check_api_surface.main([str(tmp_path)]) == 1
+
+
+def test_scoped_collective_and_docstring_mention_pass(tmp_path):
+    ok = tmp_path / "src" / "repro" / "comm"
+    ok.mkdir(parents=True)
+    (ok / "impl.py").write_text(
+        "from jax import lax\n"
+        "from repro.comm.primitives import scoped\n"
+        "def f(x):\n"
+        "    \"\"\"Same result as ``lax.psum(x, axes)``.\"\"\"\n"
+        "    return scoped(lax.psum, x, ('pod', 'data'))\n")
+    assert check_api_surface.unscoped_violations(tmp_path) == []
+    assert check_api_surface.main([str(tmp_path)]) == 0
+
+
+def test_collective_outside_scoped_paths_left_to_raw_rule(tmp_path):
+    d = tmp_path / "src" / "repro" / "bench"
+    d.mkdir(parents=True)
+    (d / "sweep.py").write_text(
+        "from jax import lax\n"
+        "def f(x):\n"
+        "    return lax.psum(x, 'data')  # raw-collective: checksum\n")
+    assert check_api_surface.unscoped_violations(tmp_path) == []
+    assert check_api_surface.main([str(tmp_path)]) == 0
