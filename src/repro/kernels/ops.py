@@ -12,7 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import flash_attention_lse
 from repro.kernels.lru_scan import lru_scan_pallas
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.quant import q4_matmul_pallas
@@ -38,20 +38,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, q_offset: int = 0,
                     block_q: int = 128, block_kv: int = 128,
                     interpret: Optional[bool] = None):
-    """q: (B, H, Tq, hd); k, v: (B, KV, Tkv, hd)."""
+    """q: (B, H, Tq, hd); k, v: (B, KV, Tkv, hd); fp32 products.
+    Differentiable (fused backward kernels)."""
     interpret = _default_interpret() if interpret is None else interpret
-    Tq, Tkv = q.shape[2], k.shape[2]
-    bq = min(block_q, Tq)
-    bkv = min(block_kv, Tkv)
-    qp, pq = _pad_to(q, bq, 2)
-    kp, pk = _pad_to(k, bkv, 2)
-    vp, _ = _pad_to(v, bkv, 2)
-    # padded kv positions are masked out by causality only if they come after
-    # every real q position — true here because kv padding extends the tail.
-    out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
-                                 q_offset=q_offset, block_q=bq, block_kv=bkv,
-                                 interpret=interpret)
-    return out[:, :, :Tq]
+    out, _ = flash_attention_lse(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=causal, window=window,
+        q_offset=q_offset, block_q=block_q, block_kv=block_kv,
+        mxu=jnp.float32, interpret=interpret)
+    return out.transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",

@@ -15,18 +15,27 @@ at import whether these tests exist would collect different tests from its
 siblings.  Keep every such compile in this one file.
 """
 
+import importlib.util
+import json
 import os
+import pathlib
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+import repro.models.attention as attention
 from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_lse
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,14 +52,20 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *args):
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args, kernels: int = 1):
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') >= kernels
     return compiled
 
 
@@ -101,3 +116,82 @@ def test_lru_scan_compiles_at_recurrentgemma_width(one_chip):
     row of a loaded block has no TPU lowering, a row of a ref has."""
     a = _spec(one_chip, (1, 2048, 4096))
     _compile(lambda a, x: ops.lru_scan(a, x, interpret=False), a, a)
+
+
+@pytest.mark.parametrize("heads", [(36, 4), (32, 8)])
+def test_fused_attention_fwd_bwd_compile_at_cell_heads(one_chip, heads):
+    """The forward, dq and dk/dv kernels at both benchmark cells' attention
+    (starcoder2-7b 36/4 heads, mistral-nemo 32/8; 4096 tokens, head_dim
+    128, batch 1), with the blocks and product dtypes ``models/attention``
+    gives them on a TPU."""
+    H, KV = heads
+    T, hd = 4096, 128
+    bq, bkv = attention._kernel_blocks(T, T)
+    mxu, pv = attention._kernel_dtypes(jnp.float32, False)
+
+    def loss(q, k, v):
+        o = flash_attention_lse(q, k, v, block_q=bq, block_kv=bkv, mxu=mxu,
+                                pv=pv, interpret=False)[0]
+        return jnp.sum(o)
+
+    kv = _spec(one_chip, (1, T, KV, hd))
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), _spec(one_chip, (1, T, H, hd)),
+             kv, kv, kernels=3)
+
+
+def _sc2_step(topo):
+    """sc2-train-4k-1chip's train step (its configuration file, mapped to
+    a ``ModelConfig`` as the benchmark's training cells map it) over one
+    described chip, and its state and batch as shapes."""
+    from repro.core.topology import MeshTopology
+    from repro.runtime.steps import make_train_step
+    from repro.substrate.compat import make_mesh
+
+    spec = importlib.util.spec_from_file_location(
+        "tpu_compile_train_cells", ROOT / "benchmark/drivers/train.py")
+    cells = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = cells
+    spec.loader.exec_module(cells)
+    config = json.loads(
+        (ROOT / "benchmark/configs/starcoder2-7b-share1.json").read_text())
+    axes = config["mesh"]
+    mesh = make_mesh(tuple(axes.values()), tuple(axes),
+                     devices=topo.devices[:1])
+    opt = config["optimizer"]
+    bundle = make_train_step(
+        cells.model_config(config), MeshTopology(dict(axes)), mesh,
+        mode=config["mode"], lr=opt["lr"], weight_decay=opt["weight_decay"],
+        clip=opt["clip"], compute_dtype=jnp.float32)
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             bundle.state_specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    params = jax.eval_shape(bundle.model.init_params)
+    state = {"params": params, "m": params, "v": params,
+             "step": jax.ShapeDtypeStruct((), jnp.int32)}
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        state, shardings)
+    tokens = jax.ShapeDtypeStruct(
+        (1, 4096), jnp.int32,
+        sharding=NamedSharding(mesh, bundle.batch_spec["tokens"]))
+    return bundle.fn, state, {"tokens": tokens}
+
+
+def test_sc2_train_step_takes_the_fused_attention(topo, monkeypatch):
+    """One train step of the sc2 cell compiled for a v5e chip through the
+    fused kernels (forward, its remat, dq, dk/dv) needs less memory for
+    its temporaries than through the KV-block scan, whose autodiff keeps
+    every KV block's fp32 probabilities.  The program's backend check sees
+    this CPU, so the test steers it to the TPU branch."""
+    def compile_step():
+        step, state, batch = _sc2_step(topo)
+        return jax.jit(step, donate_argnums=(0,)).lower(state,
+                                                        batch).compile()
+
+    scan = compile_step()
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    fused = compile_step()
+    assert "tpu_custom_call" not in scan.as_text()
+    assert fused.as_text().count('custom_call_target="tpu_custom_call"') == 4
+    assert fused.memory_analysis().temp_size_in_bytes < \
+        scan.memory_analysis().temp_size_in_bytes / 2
