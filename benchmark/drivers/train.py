@@ -51,7 +51,6 @@ HERE = pathlib.Path(__file__).resolve().parents[1]
 FIRST_STEPS = 3
 POOL = 4        # distinct batches a run places and cycles through
 IN_FLIGHT = 2   # steps queued behind the oldest unread one
-ACTS = {"gelu_pytorch_tanh": "gelu", "silu": "swiglu"}
 
 
 def _load(path: pathlib.Path):
@@ -68,20 +67,13 @@ def reference_module(config: dict):
 
 
 tokens = _load(HERE / "tokens.py")
+published = _load(HERE / "published.py")
 
 
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv=config["num_key_value_heads"], head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        act=ACTS[config["hidden_act"]], norm_eps=config["rms_norm_eps"],
-        rope_theta=config["rope_theta"],
-        tie_embeddings=config["tie_word_embeddings"])
+def model_config(config: dict, seq_len: int | None = None):
+    """The program's ``ModelConfig`` for a configuration file, for runs of
+    sequences of at most ``seq_len`` tokens."""
+    return published.from_published(config, seq_len)
 
 
 def n_chips(config: dict) -> int:
@@ -153,7 +145,8 @@ class Program:
                               devices=self.devices)
         opt = config["optimizer"]
         bundle = make_train_step(
-            model_config(config), topo, self.mesh, mode=config["mode"],
+            model_config(config, traffic["seq_len"]), topo, self.mesh,
+            mode=config["mode"],
             lr=opt["lr"], weight_decay=opt["weight_decay"], clip=opt["clip"],
             compute_dtype=compute_dtype)
         fn = bundle.fn if wrap_step is None else wrap_step(bundle.fn)

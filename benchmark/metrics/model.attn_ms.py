@@ -2,7 +2,7 @@
 operations under the ``attn`` scope (``models/attention.py:attn_block``):
 pre-norm, q/k/v/o projections, RoPE and the KV-block scan, forward, remat
 and backward (``benchmark/scopes.py``). Nothing to read where the program
-names no layer. Ops without a name of their own count where
+names no such layer. Ops without a name of their own count where
 ``scopes.instructions`` places them; the ``scopes`` line gives that part as
 ``borrowed_ns``."""
 

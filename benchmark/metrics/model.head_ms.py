@@ -2,7 +2,7 @@
 operations under the ``head`` scope (``models/transformer.py:_loss``): the
 final norm, the head product and the chunked cross-entropy, forward and
 backward (``benchmark/scopes.py``). Nothing to read where the program names
-no layer. Ops without a name of their own count where
+no such layer. Ops without a name of their own count where
 ``scopes.instructions`` places them; the ``scopes`` line gives that part as
 ``borrowed_ns``."""
 

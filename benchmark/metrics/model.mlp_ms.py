@@ -2,7 +2,7 @@
 operations under the ``mlp`` scope (``models/layers.py:ffn``): pre-norm,
 in/gate/out projections and the activation, forward, remat and backward
 (``benchmark/scopes.py``). Nothing to read where the program names no
-layer. Ops without a name of their own count where ``scopes.instructions``
+such layer. Ops without a name of their own count where ``scopes.instructions``
 places them; the ``scopes`` line gives that part as ``borrowed_ns``."""
 
 from benchmark.scopes import layer_ms

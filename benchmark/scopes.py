@@ -2,8 +2,9 @@
 read from the program's named scopes.
 
 The program names its device work with ``jax.named_scope``: ``embed``,
-``attn``, ``mlp`` and ``head`` in the models, ``optimizer`` in the train
-step, and ``comm.<primitive>[<axes>]`` on every collective
+``attn``, ``mlp`` (or ``moe`` for an expert layer) and ``head`` in the
+models, ``optimizer`` in the train step, and ``comm.<primitive>[<axes>]``
+on every collective
 (``repro.comm.primitives.scoped``; ``<axes>`` are the mesh axes it spans).
 The compiled module keeps each scope as a component of an instruction's
 ``metadata={op_name="..."}``, under the wrappers of autodiff and remat
@@ -27,7 +28,9 @@ on a small trace recorded from a chip:
    * **layer time**: each op that computes (as ``trace.device_summary``
      counts it: not control flow, and not a collective unless it also
      multiplies matrices) goes to the innermost of :data:`LAYERS` in its
-     op_name, or to ``unscoped``; each layer's time is also split into
+     op_name, or to ``unscoped``, and to the first name of its op_name
+     that carries a layer, which :func:`scope_ms` reads a part of a
+     block from (``moe/dispatch``); each layer's time is also split into
      ``forward``, ``remat`` (under ``rematted_computation``) and
      ``backward`` (under ``transpose(...)``), and the part its ops took
      from a body or a neighbour, not from their own op_name, is reported
@@ -43,7 +46,8 @@ process.  The per-layer readers ``benchmark/metrics/model.*``,
 ``train.optimizer_ms`` and ``comm.node_ms``/``comm.bridge_ms`` import this
 module as ``benchmark.scopes`` (``run.py`` puts the repository's root on
 ``sys.path``), so they share the one result through ``sys.modules``.  A
-program without the scopes reads nothing: the readers then return
+program without the scopes reads nothing, and a layer or part of a block
+that no op of the step carries reads nothing: the readers then return
 ``None``.
 """
 
@@ -59,7 +63,7 @@ import time
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 TRACE_DIR = ROOT / ".bench_trace"
-LAYERS = ("embed", "attn", "mlp", "head", "optimizer")
+LAYERS = ("embed", "attn", "mlp", "moe", "head", "optimizer")
 UNSCOPED = "unscoped"
 TIERS = ("node", "bridge")
 PHASES = ("forward", "remat", "backward")
@@ -87,28 +91,38 @@ def _trace_module():
 # Names
 # ---------------------------------------------------------------------------
 
+def named(op_name: str) -> str | None:
+    """The first name of ``op_name`` (names joined by ``;``) that carries
+    one of :data:`LAYERS`."""
+    return next((one for one in op_name.split(";") if _LAYER.search(one)),
+                None)
+
+
 def layer_of(op_name: str) -> str | None:
-    """The innermost of :data:`LAYERS` in the first name of ``op_name``
-    that carries one."""
-    for one in op_name.split(";"):
-        found = _LAYER.findall(one)
-        if found:
-            return found[-1]
-    return None
+    """The innermost of :data:`LAYERS` in :func:`named`."""
+    one = named(op_name)
+    return _LAYER.findall(one)[-1] if one else None
 
 
 def phase_of(op_name: str) -> str:
-    """``remat``, ``backward`` or ``forward``, from the wrappers of the
-    first name that carries a layer: a recomputation runs inside the
-    backward pass, under ``transpose(...)`` and ``rematted_computation``."""
-    for one in op_name.split(";"):
-        if _LAYER.search(one):
-            if "rematted_computation" in one:
-                return "remat"
-            if "transpose(" in one:
-                return "backward"
-            return "forward"
+    """``remat``, ``backward`` or ``forward``, from the wrappers of
+    :func:`named`: a recomputation runs inside the backward pass, under
+    ``transpose(...)`` and ``rematted_computation``."""
+    one = named(op_name) or ""
+    if "rematted_computation" in one:
+        return "remat"
+    if "transpose(" in one:
+        return "backward"
     return "forward"
+
+
+def in_scope(name: str, path: str) -> bool:
+    """Whether ``path`` (``moe/dispatch``) is a run of whole components of
+    ``name`` once the wrappers of autodiff and remat are taken off
+    (``transpose(jvp(moe))/dispatch`` reads ``moe/dispatch``)."""
+    bare = re.sub(r"[\w.\-]*\(|\)", "", name)
+    return re.search(r"(?:^|/)" + re.escape(path) + r"(?=/|$)",
+                     bare) is not None
 
 
 def comm_scopes(op_name: str) -> list[tuple[str, tuple[str, ...]]]:
@@ -311,7 +325,9 @@ def reduce(trace: dict, op_names: dict, tiers: dict | None = None,
     ``trace`` is what ``trace.extract`` gives, ``op_names``, ``tiers`` and
     ``borrowed`` what :func:`instructions` gives; ``busiest`` names the
     device (``trace.reduce``'s ``busiest`` where not given).  Returns
-    ``layers_ns`` (:data:`LAYERS` and ``unscoped``), ``phases_ns`` per
+    ``layers_ns`` (:data:`LAYERS` and ``unscoped``), ``named_ns`` (the
+    time of the ops under each :func:`named` of the module's op_names,
+    0 for one no op in the window carries), ``phases_ns`` per
     layer, ``borrowed_ns`` (the part of each layer's time whose ops took
     their layer from a ``body`` or a ``neighbour``, not their own name),
     ``tiers_ns``, ``tier_union_ns`` (node and bridge together),
@@ -334,6 +350,8 @@ def reduce(trace: dict, op_names: dict, tiers: dict | None = None,
     lent = {k: dict.fromkeys(LAYERS, 0.0) for k in ("body", "neighbour")}
     unscoped: dict[str, float] = collections.Counter()
     lent_ops: dict[str, float] = collections.Counter()
+    by_name = dict.fromkeys(
+        {named(v) for v in op_names.values()} - {None}, 0.0)
     for name, op, typ, s, e in dev["ops"]:
         d = min(e, hi) - max(s, lo)
         if d <= 0 or op in tr.CONTROL:
@@ -347,6 +365,7 @@ def reduce(trace: dict, op_names: dict, tiers: dict | None = None,
             unscoped[f"{name} {typ}"] += d
             continue
         layers[layer] += d
+        by_name[named(op_name)] += d
         phases[layer][phase_of(op_name)] += d
         if name in borrowed:
             lent[borrowed[name]][layer] += d
@@ -368,6 +387,7 @@ def reduce(trace: dict, op_names: dict, tiers: dict | None = None,
         "window_ns": hi - lo,
         "device": busiest,
         "layers_ns": layers,
+        "named_ns": by_name,
         "phases_ns": phases,
         "borrowed_ns": lent,
         "tiers_ns": {t: tr.length(v) for t, v in split.items()},
@@ -402,17 +422,34 @@ def measure(view) -> dict | None:
                  busiest=view.trace["busiest"])
     out["steps"] = view.raw["steps"]
     out["parse_s"] = time.monotonic() - t0
-    print("scopes " + json.dumps(out), file=sys.stderr)
+    print("scopes " + json.dumps(
+        {k: v for k, v in out.items() if k != "named_ns"}), file=sys.stderr)
     measure.cache = (key, out)
     return out
 
 
 def layer_ms(view, layer: str) -> float | None:
-    """Milliseconds per step of ``layer`` on the busiest device."""
+    """Milliseconds per step of ``layer`` on the busiest device; ``None``
+    where no op of the step carries it."""
     out = measure(view)
-    if out is None or not out["scoped"]["layers"]:
+    if out is None or not any(layer_of(n) == layer
+                              for n in out["named_ns"]):
         return None
     return out["layers_ns"][layer] / 1e6 / out["steps"]
+
+
+def scope_ms(view, path: str) -> float | None:
+    """Milliseconds per step on the busiest device of the ops whose
+    :func:`named` holds ``path`` (:func:`in_scope`): a part of a block,
+    such as ``moe/dispatch``; ``None`` where no op of the step carries
+    it."""
+    out = measure(view)
+    if out is None:
+        return None
+    mine = [ns for n, ns in out["named_ns"].items() if in_scope(n, path)]
+    if not mine:
+        return None
+    return sum(mine) / 1e6 / out["steps"]
 
 
 def tier_ms(view, tier_name: str) -> float | None:

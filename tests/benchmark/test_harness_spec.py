@@ -90,22 +90,106 @@ def test_batches_are_a_function_of_the_seed():
     np.testing.assert_array_equal(w, tokens.seed_words(seed))
 
 
+# A 4-layer pipeline stage of Qwen3-30B-A3B at its published widths, with
+# an eighth of its vocabulary (151936 / 8).
+QWEN3_STAGE = {
+    "name": "qwen3-30b-a3b-stage", "model_type": "qwen3_moe",
+    "hidden_size": 2048, "intermediate_size": 6144,
+    "moe_intermediate_size": 768, "num_experts": 128,
+    "num_experts_per_tok": 8, "norm_topk_prob": True,
+    "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
+    "num_hidden_layers": 4, "vocab_size": 18992, "hidden_act": "silu",
+    "rms_norm_eps": 1e-06, "rope_theta": 1000000.0,
+    "tie_word_embeddings": False, "sliding_window": None,
+    "use_sliding_window": False, "max_window_layers": 48}
+
+
+def _config(name):
+    if name == QWEN3_STAGE["name"]:
+        return dict(QWEN3_STAGE)
+    path = ROOT / "benchmark" / "configs" / f"{name}.json"
+    if not path.is_file():
+        path = ROOT / "tests" / "benchmark" / f"{name}.json"
+    return json.loads(path.read_text())
+
+
 @pytest.mark.parametrize("name", ["starcoder2-7b-share1",
-                                  "mistral-nemo-12b-hier2x2"])
+                                  "mistral-nemo-12b-hier2x2", "tiny_moe",
+                                  "qwen3-30b-a3b-stage"])
 def test_flops_parameter_term_matches_param_count(name):
-    """``ModelConfig.param_count`` counts the embedding twice when untied
-    (lookup and head) and four d-vectors of norm per layer; net of the
-    lookup and the norms it is the FLOPs count's matrix parameters."""
+    """``ModelConfig.active_param_count`` (all parameters for a dense
+    model; for an expert model those of the experts a token goes to, and
+    the router) counts the embedding twice when untied (lookup and head)
+    and four d-vectors of norm per layer; net of the lookup and the norms
+    it is the FLOPs count's matrix parameters."""
     flops = load("benchmark/flops.py")
     train = load("benchmark/drivers/train.py")
-    cfg = json.loads(
-        (ROOT / "benchmark" / "configs" / f"{name}.json").read_text())
-    mc = train.model_config(cfg)
+    cfg = _config(name)
+    mc = train.model_config(cfg, 4096)
     d, L, V = cfg["hidden_size"], cfg["num_hidden_layers"], cfg["vocab_size"]
-    assert flops.matmul_params(cfg) == mc.param_count() - V * d - 4 * d * L
+    assert flops.matmul_params(cfg) == \
+        mc.active_param_count() - V * d - 4 * d * L
     per_token = flops.train_flops_per_token(cfg, 4096)
     assert per_token == 6 * flops.matmul_params(cfg) + \
         12 * L * cfg["num_attention_heads"] * cfg["head_dim"] * 4096
+
+
+@pytest.mark.parametrize("name,per_token", [
+    ("starcoder2-7b-share1", 1868562432.0),
+    ("mistral-nemo-12b-hier2x2", 4177526784.0),
+    # the router's 2048 x 128 per layer on top of the dense formula's
+    # 2,397,634,560 with intermediate_size 6144 = 8 x 768
+    ("qwen3-30b-a3b-stage", 2403926016.0),
+])
+def test_flops_per_token(name, per_token):
+    """The count each cell's ``train.mfu`` divides by, as a literal: the
+    two configuration files give what they gave before layers were
+    counted by kind."""
+    flops = load("benchmark/flops.py")
+    assert flops.train_flops_per_token(_config(name), 4096) == per_token
+
+
+def test_flops_count_the_experts_a_token_goes_to():
+    """A Mixtral-shaped layer (8 experts of intermediate_size 14336, top 2)
+    multiplies by two experts and the router, not by one MLP of
+    intermediate_size; a layer ``mlp_only_layers`` lists stays dense."""
+    flops = load("benchmark/flops.py")
+    d, ff = 4096, 14336
+    mixtral = {"hidden_size": d, "intermediate_size": ff,
+               "num_local_experts": 8, "num_experts_per_tok": 2,
+               "num_attention_heads": 32, "num_key_value_heads": 8,
+               "head_dim": 128, "num_hidden_layers": 1, "vocab_size": 1,
+               "hidden_act": "silu"}
+    attn = d * (32 + 16) * 128 + 32 * 128 * d
+    assert flops.matmul_params(mixtral) == attn + 2 * 3 * d * ff + d * 8 + d
+    dense = dict(mixtral, num_local_experts=0)
+    assert flops.matmul_params(dense) == attn + 3 * d * ff + d
+    stage = dict(QWEN3_STAGE, mlp_only_layers=[0])
+    assert flops.matmul_params(stage) - flops.matmul_params(QWEN3_STAGE) == \
+        3 * 2048 * 6144 - (8 * 3 * 2048 * 768 + 2048 * 128)
+
+
+def test_flops_cut_attention_to_the_window_of_windowed_layers():
+    """Only layers the configuration marks as windowed see fewer keys than
+    the sequence: every layer under a bare ``sliding_window``; none while
+    ``use_sliding_window`` is false; those ``layer_types`` marks."""
+    flops = load("benchmark/flops.py")
+    cfg = dict(QWEN3_STAGE, sliding_window=1024)
+    per_key = 12 * 32 * 128
+    base = 6.0 * flops.matmul_params(cfg)
+    assert flops.train_flops_per_token(cfg, 4096) == base + 4 * per_key * 4096
+    on = dict(cfg, use_sliding_window=True, max_window_layers=2)
+    assert flops.train_flops_per_token(on, 4096) == \
+        base + per_key * (2 * 4096 + 2 * 1024)
+    typed = dict(cfg, layer_types=["sliding_attention", "full_attention"] * 2)
+    assert flops.train_flops_per_token(typed, 4096) == \
+        base + per_key * (2 * 4096 + 2 * 1024)
+    bare = {k: v for k, v in cfg.items()
+            if k not in ("use_sliding_window", "max_window_layers")}
+    assert flops.train_flops_per_token(bare, 4096) == base + 4 * per_key * 1024
+    assert flops.train_flops_per_token(bare, 512) == \
+        6.0 * flops.matmul_params(bare) + 4 * per_key * 512
 
 
 def test_peaks_table_names_its_source():

@@ -182,8 +182,8 @@ def test_hand_made_reduction():
     # fusion.14 counts 120-130 and 195-200 (clipped to the window), its
     # copies 180-190
     assert r["layers_ns"] == {"embed": 28, "attn": 15, "mlp": 35 + 10,
-                              "head": 15, "optimizer": 10 + 5 + 10,
-                              "unscoped": 5}
+                              "moe": 0, "head": 15,
+                              "optimizer": 10 + 5 + 10, "unscoped": 5}
     assert r["phases_ns"]["mlp"] == {"forward": 10, "remat": 35,
                                      "backward": 0}
     assert r["phases_ns"]["attn"] == {"forward": 0, "remat": 0,
@@ -193,9 +193,9 @@ def test_hand_made_reduction():
     # the unnamed fusion's 15 ns came from its body, the copies' 10 from
     # the optimizer's update they feed and are fed by
     assert r["borrowed_ns"] == {
-        "body": {"embed": 0, "attn": 15, "mlp": 0, "head": 0,
+        "body": {"embed": 0, "attn": 15, "mlp": 0, "moe": 0, "head": 0,
                  "optimizer": 0},
-        "neighbour": {"embed": 0, "attn": 0, "mlp": 0, "head": 0,
+        "neighbour": {"embed": 0, "attn": 0, "mlp": 0, "moe": 0, "head": 0,
                       "optimizer": 10}}
     assert r["own_coverage"] == pytest.approx(103 / 133)
     assert r["borrowed_ops"] == [["fusion.8 f32[8] attn body", 15],
@@ -282,11 +282,14 @@ def test_recorded_names_cover_the_trace(recorded):
     assert recorded["tiers"] and set(recorded["tiers"].values()) == {"node"}
 
 
+DENSE = ("embed", "attn", "mlp", "head", "optimizer")
+
+
 def test_recorded_layers_and_tiers(recorded):
-    """Every chip: the five layers hold at least 97% of the compute time
-    (at least 95% by the ops' own names, the rest unnamed slices and
-    stacks of the layer scan's weights and gradients that take their
-    neighbour's layer), the two tiers together are exactly the collective
+    """Every chip: the five layers of a dense model hold at least 97% of
+    the compute time (at least 95% by the ops' own names, the rest unnamed
+    slices and stacks of the layer scan's weights and gradients that take
+    their neighbour's layer), the two tiers together are exactly the collective
     time that ``comm.collective_ms`` counts, the on-node stage outweighs
     the bridge, and the op names show the forward, the recomputation and
     the backward pass of attention and MLP, and the embedding's
@@ -299,7 +302,8 @@ def test_recorded_layers_and_tiers(recorded):
         r = sc.reduce(recorded, recorded["op_names"], recorded["tiers"],
                       recorded["borrowed"], busiest=plane)
         assert r["scoped"] == {"layers": True, "comm": True}
-        assert all(r["layers_ns"][k] > 0 for k in sc.LAYERS)
+        assert all(r["layers_ns"][k] > 0 for k in DENSE)
+        assert r["layers_ns"]["moe"] == 0
         assert r["coverage"] >= 0.97
         assert 0.95 <= r["own_coverage"] < r["coverage"]
         lent = r["borrowed_ns"]
@@ -326,3 +330,93 @@ def test_recorded_without_scopes(recorded):
     assert r["layers_ns"]["unscoped"] == r["compute_ns"] > 0
     assert r["tiers_ns"] == {"node": 0, "bridge": 0}
     assert r["collective_ns"] > 0
+
+
+def test_recorded_reduces_to_the_same_numbers(recorded):
+    """The recorded 2x2 window reduces, on its first chip, to the layer and
+    tier times it gave before the ``moe`` layer and :func:`scope_ms`
+    existed: no op of a dense model is read differently."""
+    sc = load("benchmark/scopes.py")
+    r = sc.reduce(recorded, recorded["op_names"], recorded["tiers"],
+                  recorded["borrowed"], busiest="/device:TPU:0")
+    assert r["layers_ns"] == {
+        "embed": 59645638.0, "attn": 300902537.0, "mlp": 191237800.0,
+        "moe": 0.0, "head": 36487362.0, "optimizer": 33782634.0,
+        "unscoped": 10845441.0}
+    assert r["tiers_ns"] == pytest.approx(
+        {"node": 373496564.0, "bridge": 69323679.88888931})
+    assert r["phases_ns"]["attn"] == {
+        "forward": 62884914.0, "remat": 97774896.0, "backward": 140242727.0}
+    assert r["compute_ns"] == 632901412.0
+    assert {sc.layer_of(n) for n in r["named_ns"]} == set(DENSE)
+    # each layer's time is the sum of the names that carry it
+    for layer in DENSE:
+        assert sum(ns for n, ns in r["named_ns"].items()
+                   if sc.layer_of(n) == layer) == \
+            pytest.approx(r["layers_ns"][layer])
+
+
+# An expert layer's parts under the scan and its remat, one under the
+# wrappers autodiff puts round the outermost scope, and attention.
+MOE_HLO = f"""HloModule jit_step, is_scheduled=true
+
+ENTRY %main.1 (z: f32[8]) -> f32[8] {{
+  %z = f32[8]{{0}} parameter(0)
+  %multiply.1 = f32[8]{{0}} multiply(%z, %z), metadata={{op_name="{S}/jvp()/while/body/moe/experts/dot_general"}}
+  %multiply.2 = f32[8]{{0}} multiply(%multiply.1, %z), metadata={{op_name="{S}/transpose(jvp())/while/body/checkpoint/rematted_computation/moe/experts/dot_general"}}
+  %multiply.3 = f32[8]{{0}} multiply(%multiply.2, %z), metadata={{op_name="{S}/transpose(jvp(moe))/dispatch/gather"}}
+  %multiply.4 = f32[8]{{0}} multiply(%multiply.3, %z), metadata={{op_name="{S}/jvp()/while/body/attn/dot_general"}}
+  ROOT %multiply.5 = f32[8]{{0}} multiply(%multiply.4, %z), metadata={{op_name="{S}/jvp()/while/body/moe/router/dot_general"}}
+}}
+"""
+
+
+def moe_trace():
+    ops = [["multiply.1", "multiply", "f32[8]", 0, 10],
+           ["multiply.2", "multiply", "f32[8]", 10, 30],
+           ["multiply.3", "multiply", "f32[8]", 30, 34],
+           ["multiply.4", "multiply", "f32[8]", 34, 50],
+           ["multiply.1", "multiply", "f32[8]", 90, 110]]
+    return {"devices": {"/device:TPU:0": {"ops": ops, "async": []}},
+            "host": [["bench.window", 0, 100]], "fused": {}}
+
+
+def test_names_of_an_expert_layer():
+    sc = load("benchmark/scopes.py")
+    assert sc.layer_of(f"{S}/jvp()/while/body/moe/experts/dot") == "moe"
+    assert sc.layer_of(f"{S}/transpose(jvp(moe))/dispatch/gather") == "moe"
+    assert sc.in_scope(f"{S}/transpose(jvp(moe))/dispatch/gather",
+                       "moe/dispatch")
+    assert sc.in_scope(f"{S}/jvp()/while/body/moe/experts/dot",
+                       "moe/experts")
+    assert not sc.in_scope(f"{S}/jvp()/while/body/moe/experts_x/dot",
+                           "moe/experts")
+    assert not sc.in_scope(f"{S}/jvp()/while/body/moe/experts/dot",
+                           "moe/dispatch")
+    assert sc.named(f"{S}/div;{S}/jvp(moe)/mul") == f"{S}/jvp(moe)/mul"
+
+
+def test_an_expert_layer_reads_by_layer_and_by_part(monkeypatch):
+    """Ops under ``moe`` count to that layer and are read a part at a
+    time with :func:`scope_ms`; a layer or a part no op of the step
+    carries reads nothing (``mlp`` here), and so does its reader."""
+    import benchmark.scopes as sc
+    tr = sc._trace_module()
+    monkeypatch.setattr(tr, "latest_xplane", lambda d: "profile.xplane.pb")
+    monkeypatch.setattr(tr, "extract", lambda path, hlo: moe_trace())
+    monkeypatch.setattr(sc.measure, "cache", None, raising=False)
+    view = _View({"hlo_text": MOE_HLO, "steps": 2},
+                 {"busiest": "/device:TPU:0"})
+    # multiply.1 counts 0-10 and 90-100 (clipped to the window)
+    assert sc.layer_ms(view, "moe") == pytest.approx((20 + 20 + 4) / 2e6)
+    assert sc.layer_ms(view, "attn") == pytest.approx(16 / 2e6)
+    assert sc.scope_ms(view, "moe/experts") == pytest.approx(40 / 2e6)
+    assert sc.scope_ms(view, "moe/dispatch") == pytest.approx(4 / 2e6)
+    # carried by the module, never run in the window
+    assert sc.scope_ms(view, "moe/router") == 0.0
+    assert sc.scope_ms(view, "moe/combine") is None
+    assert sc.layer_ms(view, "mlp") is None
+    assert sc.layer_ms(view, "embed") is None
+    assert load("benchmark/metrics/model.mlp_ms.py").read(view) is None
+    assert sc.measure(view)["phases_ns"]["moe"] == {
+        "forward": 20, "remat": 20, "backward": 4}
