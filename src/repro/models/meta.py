@@ -13,6 +13,9 @@ Sharding policy (DESIGN.md §5):
     MPI-3 shared window; gathered at use by ``ParallelCtx.gather_w``.
   * ``data_dim`` — serve-only sharded *storage* (expert dff slices): never
     gathered; the compute is written against the local slice.
+  * ``ep_dim`` — train only: the expert dim, sharded over ``ep_axes`` (the
+    data-parallel axes ``ParallelCtx.expert_axes`` picks); each chip holds
+    its own experts, never gathered, and no FSDP dim is taken besides.
 """
 
 from __future__ import annotations
@@ -35,6 +38,8 @@ class PMeta:
     tp_dim: Optional[int] = None
     fsdp_dim: Optional[int] = None
     data_dim: Optional[int] = None
+    ep_dim: Optional[int] = None
+    ep_axes: tuple[str, ...] = ()
     init: str = "normal"           # normal | out | zeros | ones | lam
     dtype: jnp.dtype = jnp.float32
 
@@ -45,7 +50,7 @@ def _resolve_fsdp(meta: PMeta, data: int, mode: str, serve: bool,
     excluding tp/data dims.  Serve: only when explicitly requested upstream
     (meta.fsdp_dim == -2 sentinel, or ``force`` — the ``serve_fsdp`` opt
     keeping serve weights in the pod-shared one-copy-per-node store)."""
-    if mode != "hier" or data <= 1:
+    if mode != "hier" or data <= 1 or meta.ep_axes:
         meta.fsdp_dim = None
         return meta
     if serve and not force and meta.fsdp_dim != -2:
@@ -138,19 +143,25 @@ def ffn_defs(cfg: ModelConfig, tp: int) -> dict[str, PMeta]:
     }
 
 
-def moe_defs(cfg: ModelConfig, tp: int, serve: bool) -> dict[str, PMeta]:
+def moe_defs(cfg: ModelConfig, tp: int, serve: bool,
+             ep_axes: tuple[str, ...] = ()) -> dict[str, PMeta]:
+    """Expert weights are stored ``(tp, E / ep_tp, ...)``: the model axis
+    factored as ``MoESpec.ep_tp``.  Train: each tp group's experts are
+    spread further over ``ep_axes`` (dim 1).  Serve: the dff slice is
+    sharded over data instead."""
     d = cfg.d_model
     spec = cfg.moe
     ep, tp_ff = spec.ep_tp(tp)
     e_loc = spec.num_experts // ep
     n_ff = spec.d_ff_expert // tp_ff
+    held = {} if serve or not ep_axes else {"ep_dim": 1, "ep_axes": ep_axes}
     return {
         "ln": PMeta((d,), init="zeros"),
         "router": PMeta((d, spec.num_experts)),
         "w_in": PMeta((tp, e_loc, d, 2, n_ff), tp_dim=0,
-                      data_dim=4 if serve else None),
+                      data_dim=4 if serve else None, **held),
         "w_out": PMeta((tp, e_loc, n_ff, d), tp_dim=0, init="out",
-                       data_dim=2 if serve else None),
+                       data_dim=2 if serve else None, **held),
     }
 
 
@@ -194,11 +205,11 @@ def rglru_defs(cfg: ModelConfig, tp: int) -> dict[str, PMeta]:
 
 
 def block_defs(kind: str, cfg: ModelConfig, tp: int, serve: bool,
-               opts=frozenset()) -> dict:
+               opts=frozenset(), ep_axes: tuple[str, ...] = ()) -> dict:
     if kind in ("attn", "local"):
         out = {"attn": attn_defs(cfg, tp, serve, opts)}
         if cfg.moe:
-            out["moe"] = moe_defs(cfg, tp, serve)
+            out["moe"] = moe_defs(cfg, tp, serve, ep_axes)
         elif cfg.d_ff:
             out["ffn"] = ffn_defs(cfg, tp)
         return out
@@ -209,7 +220,7 @@ def block_defs(kind: str, cfg: ModelConfig, tp: int, serve: bool,
     if kind == "rglru":
         out = {"rglru": rglru_defs(cfg, tp)}
         if cfg.moe:
-            out["moe"] = moe_defs(cfg, tp, serve)
+            out["moe"] = moe_defs(cfg, tp, serve, ep_axes)
         elif cfg.d_ff:
             out["ffn"] = ffn_defs(cfg, tp)
         return out
@@ -217,9 +228,11 @@ def block_defs(kind: str, cfg: ModelConfig, tp: int, serve: bool,
 
 
 def model_defs(cfg: ModelConfig, tp: int, data: int, mode: str,
-               serve: bool = False, opts=frozenset()) -> dict:
+               serve: bool = False, opts=frozenset(),
+               ep_axes: tuple[str, ...] = ()) -> dict:
     """Full meta tree.  'units' metas describe PER-LAYER shapes (they get a
-    stacked leading dim at materialization)."""
+    stacked leading dim at materialization).  ``ep_axes``: the axes the
+    train path spreads each layer's experts over."""
     d = cfg.d_model
     defs: dict = {
         "embed": PMeta((cfg.vocab_padded, d), tp_dim=0),
@@ -229,10 +242,10 @@ def model_defs(cfg: ModelConfig, tp: int, data: int, mode: str,
         defs["unembed"] = PMeta((d, cfg.vocab_padded), tp_dim=1)
     if cfg.frontend:
         defs["frontend"] = PMeta((cfg.d_frontend, d))
-    defs["units"] = {f"b{i}": block_defs(k, cfg, tp, serve, opts)
+    defs["units"] = {f"b{i}": block_defs(k, cfg, tp, serve, opts, ep_axes)
                      for i, k in enumerate(cfg.pattern)}
     if cfg.remainder_kinds:
-        defs["rem"] = {f"r{i}": block_defs(k, cfg, tp, serve, opts)
+        defs["rem"] = {f"r{i}": block_defs(k, cfg, tp, serve, opts, ep_axes)
                        for i, k in enumerate(cfg.remainder_kinds)}
     force = serve and "serve_fsdp" in opts
     return jax.tree.map(
@@ -314,6 +327,8 @@ def param_specs(defs: dict, cfg: ModelConfig, *, tp_axis: Optional[str],
             spec[meta.fsdp_dim + off] = fsdp_axis
         if meta.data_dim is not None and fsdp_axis:
             spec[meta.data_dim + off] = fsdp_axis
+        if meta.ep_dim is not None:
+            spec[meta.ep_dim + off] = meta.ep_axes
         return P(*spec)
 
     paths = jax.tree_util.tree_leaves_with_path(

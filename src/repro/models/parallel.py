@@ -16,6 +16,10 @@ Axis roles:
   * ``dp_axes``   — batch sharding (("pod","data") or ("data",)).
   * ``pod_axis``  — the bridge (slow tier); gradient reductions cross it once
                   per shard (multi-leader bridge exchange).
+  * expert axes   — on the train path an expert layer's experts are spread
+                  over data-parallel axes (``expert_axes``): each chip
+                  holds its own experts, never gathered, and tokens reach
+                  them through the alltoall (``models/moe.py``).
 
 Weight access goes through ``gather_w`` (the "load from the node's shared
 buffer": an intra-pod all-gather at use time in hier mode, identity in naive
@@ -42,6 +46,7 @@ class ParallelCtx:
     tp_axis: Optional[str] = None          # "model"
     fsdp_axes: tuple[str, ...] = ()        # ("data",) in hier mode
     dp_axes: tuple[str, ...] = ()          # ("pod","data") / ("data",)
+    dp_sizes: tuple[int, ...] = ()         # sizes of dp_axes
     pod_axis: Optional[str] = None         # "pod" on the multi-pod mesh
     tp: int = 1                            # size of tp_axis
     mode: str = "hier"                     # hier | naive
@@ -97,6 +102,50 @@ class ParallelCtx:
         (bucketed / deduped / reordered) schedule instead of issuing
         eagerly.  Bit-identical outputs; off by default."""
         return "stepgraph" in self.opts
+
+    def expert_axes(self, n_experts: int) -> tuple[str, ...]:
+        """The data-parallel axes over which a layer's ``n_experts`` are
+        spread on the train path: all of ``dp_axes`` where their size is
+        above 1 and divides ``n_experts``, else ``()`` (every chip then
+        holds every expert).  Every function that makes a ctx sets
+        ``dp_sizes``."""
+        if len(self.dp_sizes) != len(self.dp_axes):
+            raise ValueError(f"dp_sizes {self.dp_sizes} do not give the "
+                             f"sizes of dp_axes {self.dp_axes}")
+        n = 1
+        for size in self.dp_sizes:
+            n *= size
+        return self.dp_axes if n > 1 and n_experts % n == 0 else ()
+
+    def grad_sq_norm(self, grads, metas, node: Communicator,
+                     world: Communicator, **allreduce):
+        """The squared global norm of reduced, node-resident gradients,
+        every element counted exactly once.  A leaf is tiled over the axes
+        it is sharded on and replicated over the rest of the node tier:
+        its square is weighted by 1/replication and summed over the node.
+        Gradients are pod-identical after the bridge, except expert leaves
+        held apart over the pods, whose squares sum over every chip.
+        ``allreduce``: keywords of both reductions."""
+        gsq, gsq_pods = jnp.float32(0.0), None
+        for g, meta in zip(jax.tree.leaves(grads), metas):
+            held = set(meta.ep_axes)
+            if self.mode == "hier" and meta.fsdp_dim is not None:
+                held |= set(self.fsdp_axes)
+            repl = 1.0
+            if meta.tp_dim is None and self.tp_axis:
+                repl *= self.tp
+            for a, size in zip(self.dp_axes, self.dp_sizes):
+                if a != self.pod_axis and a not in held:
+                    repl *= size
+            sq = jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
+            if self.pod_axis and self.pod_axis in meta.ep_axes:
+                gsq_pods = sq if gsq_pods is None else gsq_pods + sq
+            else:
+                gsq += sq
+        gsq = node.allreduce(gsq, **allreduce)
+        if gsq_pods is not None:
+            gsq += world.allreduce(gsq_pods, **allreduce)
+        return gsq
 
     # ---- indices -----------------------------------------------------------
     @property
@@ -177,6 +226,8 @@ class ParallelCtx:
         axis.  What is left: the bridge (pod) in hier mode — plus the fsdp
         axes for the tiny fsdp-replicated leaves (norms); the full dp tier
         in naive mode; plus the tp axis for tp-replicated leaves in both.
+        An expert leaf is summed over none of the axes its experts are
+        spread over (``PMeta.ep_axes``): one chip alone holds each expert.
         Bridge axes come FIRST so the naive lowering (``lax.psum`` over
         slow + fast) matches the axes order exactly."""
         axes: tuple[str, ...] = ()
@@ -187,11 +238,12 @@ class ParallelCtx:
                 axes += tuple(self.fsdp_axes)
         else:
             axes += tuple(self.dp_axes)
+        axes = tuple(a for a in axes if a not in meta.ep_axes)
         if meta.tp_dim is None and self.tp_axis:
             axes += (self.tp_axis,)
         return axes
 
-    def _axes_comm(self, axes: tuple[str, ...]) -> Communicator:
+    def axes_comm(self, axes: tuple[str, ...]) -> Communicator:
         """The two-tier communicator that reduces over EXACTLY ``axes``:
         pod is the slow tier when present alongside fast axes, else the
         whole (single-tier) communicator."""
@@ -266,7 +318,7 @@ class ParallelCtx:
                     continue
                 comm = comms.get(axes)
                 if comm is None:
-                    comm = comms[axes] = self._axes_comm(axes)
+                    comm = comms[axes] = self.axes_comm(axes)
                 reduced.append(comm.allreduce(g, scheme="naive",
                                               result="replicated"))
             tree = jax.tree.unflatten(jax.tree.structure(grads), reduced)

@@ -79,8 +79,12 @@ class Model:
 
 
 def build(cfg: ModelConfig, ctx: ParallelCtx, data: int = 1) -> Model:
+    ep_axes = ()
+    if cfg.moe:
+        ep_axes = ctx.expert_axes(cfg.moe.num_experts
+                                  // cfg.moe.ep_tp(ctx.tp)[0])
     defs = M.model_defs(cfg, ctx.tp, data, ctx.mode, serve=False,
-                        opts=ctx.opts)
+                        opts=ctx.opts, ep_axes=ep_axes)
     serve_defs = M.model_defs(cfg, ctx.tp, data, ctx.mode, serve=True,
                               opts=ctx.opts)
     return Model(cfg, ctx, defs, serve_defs)
@@ -90,17 +94,20 @@ def build(cfg: ModelConfig, ctx: ParallelCtx, data: int = 1) -> Model:
 # Blocks dispatch
 # ---------------------------------------------------------------------------
 
-def _mix(kind: str, x, p, mt, ctx, cfg, *, serve=False):
-    """Channel-mixing half of attn/local/rglru blocks."""
+def _mix(kind: str, x, p, mt, ctx, cfg, *, serve=False, stats=None):
+    """Channel-mixing half of attn/local/rglru blocks.  ``stats`` (a list)
+    collects each expert layer's routed-row count per expert."""
     if cfg.moe:
-        return moe_block(x, p["moe"], mt["moe"], ctx, cfg, serve=serve)
+        return moe_block(x, p["moe"], mt["moe"], ctx, cfg, serve=serve,
+                         stats=stats)
     if not cfg.d_ff:
         return x
     f = ffn_decode if serve else ffn
     return f(x, p["ffn"], mt["ffn"], ctx, act=cfg.act, eps=cfg.norm_eps)
 
 
-def _block_train(kind: str, x, p, mt, ctx, cfg, *, return_state=False):
+def _block_train(kind: str, x, p, mt, ctx, cfg, *, return_state=False,
+                 stats=None):
     if kind in ("attn", "local"):
         window = cfg.window if kind == "local" else None
         mode = M.attn_mode_for(cfg, ctx.tp)
@@ -110,7 +117,7 @@ def _block_train(kind: str, x, p, mt, ctx, cfg, *, return_state=False):
         else:
             x = attn_block(x, p["attn"], mt["attn"], ctx, cfg, mode=mode,
                            window=window)
-        x = _mix(kind, x, p, mt, ctx, cfg)
+        x = _mix(kind, x, p, mt, ctx, cfg, stats=stats)
         return (x, {"k": kv[0], "v": kv[1]}) if return_state else x
     if kind == "mlstm":
         chunk = MLSTM_CHUNK
@@ -131,7 +138,7 @@ def _block_train(kind: str, x, p, mt, ctx, cfg, *, return_state=False):
             x, st = out
             x = _mix(kind, x, p, mt, ctx, cfg)
             return x, st
-        x = _mix(kind, out, p, mt, ctx, cfg)
+        x = _mix(kind, out, p, mt, ctx, cfg, stats=stats)
         return x
     raise ValueError(kind)
 
@@ -335,7 +342,7 @@ def _scan_units(cfg, ctx, defs, params, x, *, collect_state=False,
     kinds = cfg.pattern
 
     def unit(x, pu):
-        states = {}
+        states, loads = {}, []
         for i, k in enumerate(kinds):
             key = f"b{i}"
             if collect_state:
@@ -343,8 +350,11 @@ def _scan_units(cfg, ctx, defs, params, x, *, collect_state=False,
                                      cfg, return_state=True)
                 states[key] = st
             else:
-                x = _block_train(k, x, pu[key], defs["units"][key], ctx, cfg)
-        return (x, states) if collect_state else x
+                x = _block_train(k, x, pu[key], defs["units"][key], ctx, cfg,
+                                 stats=loads)
+        if collect_state:
+            return x, states
+        return x, (jnp.stack(loads) if loads else None)
 
     if collect_state:
         def body(x, pu):
@@ -387,14 +397,12 @@ def _scan_units(cfg, ctx, defs, params, x, *, collect_state=False,
                           budget)
         return x, None
 
-    unit_r = remat(unit)
-    x, _ = lax.scan(lambda c, pu: (unit_r(c, pu), None), x, params["units"],
-                    unroll=unroll)
-    return x, None
+    # per unit: x, and each expert layer's routed-row count per expert
+    return lax.scan(remat(unit), x, params["units"], unroll=unroll)
 
 
 def _rem_blocks(cfg, ctx, defs, params, x, *, collect_state=False, pos=None,
-                cache=None, decode=False):
+                cache=None, decode=False, stats=None):
     states = {}
     for i, k in enumerate(cfg.remainder_kinds):
         key = f"r{i}"
@@ -408,23 +416,35 @@ def _rem_blocks(cfg, ctx, defs, params, x, *, collect_state=False, pos=None,
             states[key] = st
         else:
             x = _block_train(k, x, params["rem"][key], defs["rem"][key], ctx,
-                             cfg)
+                             cfg, stats=stats)
     return x, states
 
 
-def _loss(cfg, ctx, defs, params, batch, *, unroll: int = 1):
-    """Returns (loss_sum, token_count) — local partials; caller reduces."""
+def _loss(cfg, ctx, defs, params, batch, *, unroll: int = 1,
+          stats: bool = False):
+    """Returns (loss_sum, token_count) — local partials; caller reduces.
+    With ``stats``, also each expert layer's routed-row count per expert
+    from this chip's tokens, ``(layers, num_experts)`` (``None`` for a
+    model without experts)."""
     T = (batch["frames"].shape[1] if cfg.frontend == "encodec"
          else batch["tokens"].shape[1] - 1)
     x, labels, mask = _embed_sp(cfg, ctx, defs, params, batch, T=T)
-    x, _ = _scan_units(cfg, ctx, defs, params, x, unroll=unroll)
-    x, _ = _rem_blocks(cfg, ctx, defs, params, x)
+    x, loads = _scan_units(cfg, ctx, defs, params, x, unroll=unroll)
+    rem_loads = []
+    x, _ = _rem_blocks(cfg, ctx, defs, params, x, stats=rem_loads)
     with jax.named_scope("head"):
         x = rms_norm(x, ctx.gather_w(params["final_ln"],
                                      defs["final_ln"].fsdp_dim), cfg.norm_eps)
         w_un = _unembed_weight(cfg, ctx, defs, params)
-        return unembed_xent(x, labels, mask, w_un, ctx, chunk=XENT_CHUNK,
-                            softcap=cfg.logit_softcap)
+        loss_sum, cnt = unembed_xent(x, labels, mask, w_un, ctx,
+                                     chunk=XENT_CHUNK,
+                                     softcap=cfg.logit_softcap)
+    if not stats:
+        return loss_sum, cnt
+    parts = [r[None] for r in rem_loads]
+    if loads is not None:
+        parts = [loads.reshape(-1, loads.shape[-1])] + parts
+    return loss_sum, cnt, (jnp.concatenate(parts) if parts else None)
 
 
 # ---------------------------------------------------------------------------

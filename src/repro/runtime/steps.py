@@ -37,10 +37,12 @@ from repro.configs.base import ModelConfig
 def make_ctx(topo: MeshTopology, mode: str,
              compute_dtype=jnp.bfloat16, opts=()) -> ParallelCtx:
     has_pod = "pod" in topo.axis_sizes
+    dp = ("pod", "data") if has_pod else ("data",)
     return ParallelCtx(
         tp_axis="model",
         fsdp_axes=("data",) if mode == "hier" else (),
-        dp_axes=(("pod", "data") if has_pod else ("data",)),
+        dp_axes=dp,
+        dp_sizes=tuple(topo.size(a) for a in dp),
         pod_axis="pod" if has_pod else None,
         tp=topo.size("model"),
         mode=mode,
@@ -135,10 +137,12 @@ def make_train_step(cfg: ModelConfig, topo: MeshTopology, mesh, *,
         params = state["params"]
 
         def lf(p):
-            loss, cnt = _loss(cfg, ctx, defs, p, batch, unroll=unroll)
-            return loss, cnt
+            loss, cnt, loads = _loss(cfg, ctx, defs, p, batch, unroll=unroll,
+                                     stats=True)
+            return loss, (cnt, loads)
 
-        (loss_sum, cnt), grads = jax.value_and_grad(lf, has_aux=True)(params)
+        (loss_sum, (cnt, loads)), grads = jax.value_and_grad(
+            lf, has_aux=True)(params)
         # scheme="auto": the tuning table picks the reduction schedule per
         # topology/size; the replicated constraint (not a scheme name)
         # keeps the result a plain per-rank scalar, never a window.
@@ -168,22 +172,8 @@ def make_train_step(cfg: ModelConfig, topo: MeshTopology, mesh, *,
         with jax.named_scope("optimizer"):
             grads = jax.tree.map(lambda g: g / cnt_g, grads)
 
-            # global grad norm: each leaf is tiled over the axes it is
-            # sharded on and replicated over the rest of the node tier —
-            # weight the square by 1/replication so the reduction counts
-            # every element exactly once.  Node-local: grads are
-            # pod-identical after the bridge.
-            gsq = jnp.float32(0.0)
-            for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
-                repl = 1.0
-                if meta.tp_dim is None and "model" in topo.axis_sizes:
-                    repl *= topo.size("model")
-                data_sharded = (ctx.mode == "hier"
-                                and meta.fsdp_dim is not None)
-                if not data_sharded and "data" in topo.axis_sizes:
-                    repl *= topo.size("data")
-                gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
-            gsq = node.allreduce(gsq, result="replicated")
+            gsq = ctx.grad_sq_norm(grads, meta_leaves, node, world,
+                                   result="replicated")
             gnorm = jnp.sqrt(gsq)
             scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
             grads = jax.tree.map(lambda g: g * scale, grads)
@@ -194,12 +184,19 @@ def make_train_step(cfg: ModelConfig, topo: MeshTopology, mesh, *,
         new_state = {"params": new_params, "m": new_m, "v": new_v,
                      "step": state["step"] + 1}
         metrics = {"loss": loss_g / cnt_g, "gnorm": gnorm, "tokens": cnt_g}
+        if loads is not None:
+            # the busiest expert's routed rows over the mean, worst layer
+            loads = world.allreduce(loads, result="replicated")
+            metrics["moe_load_max"] = jnp.max(
+                jnp.max(loads, axis=1) / jnp.mean(loads, axis=1))
         return new_state, metrics
 
+    metric_specs = {"loss": P(), "gnorm": P(), "tokens": P()}
+    if cfg.moe:
+        metric_specs["moe_load_max"] = P()
     smapped = shard_map(
         body, mesh=mesh, in_specs=(state_specs, bspec),
-        out_specs=(state_specs, {"loss": P(), "gnorm": P(), "tokens": P()}),
-        check_vma=False)
+        out_specs=(state_specs, metric_specs), check_vma=False)
     return TrainStepBundle(fn=smapped, state_specs=state_specs,
                            batch_spec=bspec, model=model, mesh=mesh)
 
@@ -235,10 +232,13 @@ def cluster_ctx(vc, *, mode: str = "hier", compute_dtype=jnp.float32,
         # issue early: the prefetch schedule degrades to the eager path
         # (same program) instead of paying the handle plumbing for no-ops
         opts = tuple(o for o in opts if not str(o).startswith("prefetch"))
+    dp = ((pod,) + store) if pod else store
+    sizes = dict(zip(vc.axis_names, vc.axis_shapes))
     return ParallelCtx(
         tp_axis=tp_axis,
         fsdp_axes=store if mode == "hier" else (),
-        dp_axes=((pod,) + store) if pod else store,
+        dp_axes=dp,
+        dp_sizes=tuple(sizes[a] for a in dp),
         pod_axis=pod,
         tp=vc.fast_shape[-1] if tp_axis else 1,
         mode=mode, compute_dtype=compute_dtype, opts=frozenset(opts))
@@ -319,15 +319,8 @@ def make_cluster_train_step(cfg: ModelConfig, vc, *, mode: str = "hier",
             grads = ctx.reduce_grads(grads, meta_leaves)
         with jax.named_scope("optimizer"):
             grads = jax.tree.map(lambda g: g / cnt_g, grads)
-            gsq = jnp.float32(0.0)
-            for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
-                repl = 1.0
-                if meta.tp_dim is None and ctx.tp_axis:
-                    repl *= ctx.tp
-                if meta.fsdp_dim is None or ctx.mode != "hier":
-                    repl *= data
-                gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
-            gsq = node.allreduce(gsq, result="replicated")
+            gsq = ctx.grad_sq_norm(grads, meta_leaves, node, world,
+                                   result="replicated")
             gnorm = jnp.sqrt(gsq)
             scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
             grads = jax.tree.map(lambda g: g * scale, grads)
@@ -419,15 +412,8 @@ def make_step_bench(cfg: ModelConfig, vc, *, opts=(), unroll: int = 1,
             cnt_g = world.allreduce(cnt, scheme="naive")
             grads = ctx.reduce_grads(grads, meta_leaves)
         grads = jax.tree.map(lambda g: g / cnt_g, grads)
-        gsq = jnp.float32(0.0)
-        for g, meta in zip(jax.tree.leaves(grads), meta_leaves):
-            repl = 1.0
-            if meta.tp_dim is None and ctx.tp_axis:
-                repl *= ctx.tp
-            if meta.fsdp_dim is None:
-                repl *= data
-            gsq += jnp.sum(jnp.square(g.astype(jnp.float32))) / repl
-        gsq = node.allreduce(gsq, scheme="naive")
+        gsq = ctx.grad_sq_norm(grads, meta_leaves, node, world,
+                               scheme="naive")
         gnorm = jnp.sqrt(gsq)
         scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9))
         grads = jax.tree.map(lambda g: g * scale, grads)

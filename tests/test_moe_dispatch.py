@@ -73,3 +73,59 @@ def test_route_gates_normalized():
     for i in range(32):
         want = set(np.argsort(logits[i])[-3:])
         assert set(np.asarray(idx)[i].tolist()) == want
+
+
+@given(st.integers(min_value=1, max_value=24),   # received rows
+       st.integers(min_value=1, max_value=4),    # top-k slots a row
+       st.integers(min_value=1, max_value=6),    # experts held
+       st.floats(min_value=0.0, max_value=1.0),  # share of slots held here
+       st.integers(min_value=0, max_value=3))    # seed
+@settings(max_examples=15, deadline=None)
+def test_expert_pieces_sum_every_pair(R, k, n_loc, share, seed):
+    """The train path's grouped products over every received slot, sorted
+    by expert, give every (row, held expert) pair's gated product summed
+    per row, from no pair held here to every slot; gradients as well,
+    through the gathers' own transposes."""
+    from repro.models.layers import activation
+    from repro.models.moe import experts
+
+    rng = np.random.default_rng(seed)
+    d, ff = 8, 4
+    ids = np.full((R, k), n_loc, np.int32)
+    for r in range(R):
+        held = rng.permutation(n_loc)[:k]
+        take = rng.random(len(held)) < share
+        ids[r, :len(held)] = np.where(take, held, n_loc)
+    ids = jnp.asarray(ids.reshape(-1))
+    gates = jnp.asarray(rng.random(R * k).astype(np.float32))
+    rows = jnp.asarray(rng.standard_normal((R, d)).astype(np.float32))
+    w_in = jnp.asarray(rng.standard_normal((n_loc, d, 2, ff))
+                       .astype(np.float32))
+    w_out = jnp.asarray(rng.standard_normal((n_loc, ff, d))
+                        .astype(np.float32))
+
+    def dense(rows, gates, w_in, w_out):
+        e = jnp.clip(ids, 0, n_loc - 1)
+        x = jnp.repeat(rows, k, axis=0)
+        u = jnp.einsum("pd,pdgf->pgf", x, w_in[e],
+                       precision=jax.lax.Precision.HIGHEST)
+        a = activation("silu", u[:, 0], u[:, 1])
+        out = jnp.einsum("pf,pfd->pd", a, w_out[e],
+                         precision=jax.lax.Precision.HIGHEST)
+        out = jnp.where((ids < n_loc)[:, None], out * gates[:, None], 0.0)
+        return out.reshape(R, k, d).sum(axis=1)
+
+    def grouped(rows, gates, w_in, w_out):
+        return experts(rows, ids, gates, w_in, w_out, k=k, act="silu")
+
+    # float32 on the CPU, full precision on both sides: what separates them
+    # is the order of the sums (a row's pairs), rounding alone
+    args = (rows, gates, w_in, w_out)
+    np.testing.assert_allclose(jax.jit(grouped)(*args), dense(*args),
+                               rtol=1e-5, atol=1e-5)
+    ct = jnp.asarray(rng.standard_normal((R, d)).astype(np.float32))
+    every = (0, 1, 2, 3)
+    got = jax.grad(lambda *a: jnp.sum(grouped(*a) * ct), every)(*args)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * ct), every)(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)

@@ -139,10 +139,11 @@ def test_fused_attention_fwd_bwd_compile_at_cell_heads(one_chip, heads):
              kv, kv, kernels=3)
 
 
-def _sc2_step(topo):
-    """sc2-train-4k-1chip's train step (its configuration file, mapped to
-    a ``ModelConfig`` as the benchmark's training cells map it) over one
-    described chip, and its state and batch as shapes."""
+def _cell_step(topo, config_name: str, batch: int, seq: int = 4096):
+    """A benchmark cell's train step (its configuration file, mapped to a
+    ``ModelConfig`` as the benchmark's training cells map it, for
+    sequences of ``seq`` tokens) over the configuration's mesh of described
+    chips, and its state and batch as shapes."""
     from repro.core.topology import MeshTopology
     from repro.runtime.steps import make_train_step
     from repro.substrate.compat import make_mesh
@@ -153,13 +154,15 @@ def _sc2_step(topo):
     sys.modules[spec.name] = cells
     spec.loader.exec_module(cells)
     config = json.loads(
-        (ROOT / "benchmark/configs/starcoder2-7b-share1.json").read_text())
+        (ROOT / "benchmark/configs" / f"{config_name}.json").read_text())
     axes = config["mesh"]
     mesh = make_mesh(tuple(axes.values()), tuple(axes),
-                     devices=topo.devices[:1])
+                     devices=topo.devices[:cells.n_chips(config)])
     opt = config["optimizer"]
     bundle = make_train_step(
-        cells.model_config(config), MeshTopology(dict(axes)), mesh,
+        cells.model_config(config, seq),
+        MeshTopology(dict(axes), slow_axes=("pod",) if "pod" in axes
+                     else ()), mesh,
         mode=config["mode"], lr=opt["lr"], weight_decay=opt["weight_decay"],
         clip=opt["clip"], compute_dtype=jnp.float32)
     shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -172,9 +175,14 @@ def _sc2_step(topo):
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         state, shardings)
     tokens = jax.ShapeDtypeStruct(
-        (1, 4096), jnp.int32,
+        (batch, seq + 1), jnp.int32,
         sharding=NamedSharding(mesh, bundle.batch_spec["tokens"]))
     return bundle.fn, state, {"tokens": tokens}
+
+
+def _sc2_step(topo):
+    """sc2-train-4k-1chip's train step over one described chip."""
+    return _cell_step(topo, "starcoder2-7b-share1", batch=1)
 
 
 def test_sc2_train_step_takes_the_fused_attention(topo, monkeypatch):
@@ -195,3 +203,31 @@ def test_sc2_train_step_takes_the_fused_attention(topo, monkeypatch):
     assert fused.as_text().count('custom_call_target="tpu_custom_call"') == 4
     assert fused.memory_analysis().temp_size_in_bytes < \
         scan.memory_analysis().temp_size_in_bytes / 2
+
+
+def test_qwen3_moe_stage_fits_a_v5e_2x2(topo, monkeypatch):
+    """qwen3moe-train-4k-2x2's train step, one 4096-token sequence per chip
+    of a described 2x2 v5e host, with 32 experts held per chip: the
+    compiler places it within a chip's 15.75 GiB, the experts move by
+    all-to-all (no all-gather carries an expert leaf's f32[..,2048,2,768]
+    or f32[..,768,2048] block), and the grouped products are ragged dots
+    over a static buffer of every received slot, with no conditional: the
+    step's work does not change with the routing.
+    The program's backend check sees this CPU, so the test steers it to
+    the fused attention the chip runs."""
+    import re
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    step, state, batch = _cell_step(topo, "qwen3-30b-a3b-ep2x2", batch=4)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(state,
+                                                        batch).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    assert "ragged-dot" in text
+    assert " conditional(" not in text
+    gathers = [line for line in text.splitlines()
+               if re.search(r"\ball-gather(-start)?\(", line)]
+    assert gathers
+    assert not any(re.search(r"2048,2,768\]|768,2048\]", line)
+                   for line in gathers)
